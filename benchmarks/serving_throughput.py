@@ -43,9 +43,7 @@ The ``raw_speed`` section is the PR 10 pass: the fully on-device planner
 ``sur_greedy_many``) against the retained PR 9 host-gamma plane at G in
 {1, 8, 64} with bit-identical plans asserted (the committed report carries
 the >= 1.3x bar at G = 64); donated vs non-donated wave dispatch with the
-routes bit-checked; and cold-*process* first-plan latency twice against a
-shared ``REPRO_COMPILE_CACHE_DIR`` (second process deserializes instead of
-compiling), with honesty fields when the backend lacks cache support.
+routes bit-checked.
 
 Finally the ``feedback`` section measures the online estimation loop on
 synthetic *drifted* traffic: the arms the served plans rely on degrade
@@ -70,9 +68,6 @@ import argparse
 import dataclasses
 import json
 import os
-import subprocess
-import sys
-import tempfile
 import time
 from typing import List
 
@@ -88,7 +83,13 @@ from repro.core.mc import bucket_size
 from repro.core.types import clip_probs
 from repro.data import OracleWorkload
 from repro.distributed.fault import FaultPolicy
-from repro.serving import BatchScheduler, OracleArm, PoolEngine, ThriftRouter
+from repro.serving import (
+    BatchScheduler,
+    OracleArm,
+    PoolEngine,
+    ThriftRouter,
+    configure_compile_cache,
+)
 from repro.serving import router as router_mod
 
 BATCH_SIZES = [32, 64, 128, 256, 512, 1024]
@@ -448,7 +449,7 @@ def cross_device(router, wl, budget: float, per_batch: int, make_router,
     import os
 
     import jax
-    from jax.experimental import enable_x64
+    from repro.core.x64 import x64
 
     from repro.serving import ReplicaSet
 
@@ -508,7 +509,7 @@ def cross_device(router, wl, budget: float, per_batch: int, make_router,
             jax.block_until_ready(o)
 
     wave_staged = {}
-    with enable_x64():
+    with x64():
         for R in replicas:
             shards = [
                 jax.device_put(wave_args(Bp), devs[i % len(devs)])
@@ -559,7 +560,7 @@ def cross_device(router, wl, budget: float, per_batch: int, make_router,
 
     # ---- wave-plane rows -----------------------------------------------
     wave_rows = []
-    with enable_x64():
+    with x64():
         for R in replicas:
             shards, fused = wave_staged[R]
             t_o = t_f = float("inf")
@@ -958,8 +959,7 @@ def selection_replan(num_arms: int, classes: int, history: int,
 
 # ---------------------------------------------------------------------------
 # raw_speed: the PR 10 section — fully on-device planner vs the PR 9
-# host-gamma plane, donation on/off wave-loop timings, and cold-start
-# replan latency with/without the persistent compilation cache.
+# host-gamma plane and donation on/off wave-loop timings.
 # ---------------------------------------------------------------------------
 
 
@@ -980,71 +980,10 @@ def _same_plan(a, b) -> bool:
     return True
 
 
-_COLD_START_CHILD = r"""
-import json, sys, time
-import numpy as np
-t_import0 = time.perf_counter()
-import jax
-from repro.core import sur_greedy_many
-from repro.serving.compile_cache import cache_supported, configure_compile_cache
-t_import = time.perf_counter() - t_import0
-info = configure_compile_cache()          # reads REPRO_COMPILE_CACHE_DIR
-rng = np.random.default_rng(0)
-t0 = time.perf_counter()
-sur_greedy_many(
-    rng.uniform(0.2, 0.98, (8, 12)), rng.uniform(0.05, 1.0, 12),
-    rng.uniform(0.5, 2.0, 8), 4, jax.random.key(0), np.full(8, 300),
-)
-dt = time.perf_counter() - t0
-print(json.dumps({"first_plan_s": dt, "import_s": t_import,
-                  "cache": info, "supported": cache_supported()}))
-"""
-
-
-def _cold_start_cache(repo_root: str) -> dict:
-    """Cold-process replan latency, twice against one shared persistent
-    compile-cache dir: the first process pays the XLA compile and seeds the
-    cache, the second deserializes the executable instead of compiling.
-    Honesty fields: skipped (+reason) when the backend has no cache
-    serialization support, and the raw child payloads either way."""
-    from repro.serving.compile_cache import cache_supported
-
-    if not cache_supported():
-        return {"skipped": True, "reason": "backend lacks persistent-cache "
-                "support", "supported": False}
-    out = {"skipped": False, "supported": True}
-    with tempfile.TemporaryDirectory(prefix="repro-cache-") as cache_dir:
-        env = dict(os.environ)
-        env["REPRO_COMPILE_CACHE_DIR"] = cache_dir
-        env["PYTHONPATH"] = os.path.join(repo_root, "src")
-        runs = []
-        for label in ("first", "second"):
-            proc = subprocess.run(
-                [sys.executable, "-c", _COLD_START_CHILD],
-                capture_output=True, text=True, env=env, cwd=repo_root,
-            )
-            if proc.returncode != 0:
-                return {"skipped": True, "supported": True,
-                        "reason": f"{label} child failed",
-                        "stderr": proc.stderr[-2000:]}
-            runs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
-        cache_entries = len(
-            [p for p in os.listdir(cache_dir) if not p.startswith(".")]
-        )
-    out["first_plan_s"] = runs[0]["first_plan_s"]
-    out["second_plan_s"] = runs[1]["first_plan_s"]
-    out["speedup"] = out["first_plan_s"] / out["second_plan_s"]
-    out["improved"] = bool(out["second_plan_s"] < out["first_plan_s"])
-    out["cache_entries"] = cache_entries
-    out["children"] = runs
-    return out
-
-
 def raw_speed(num_arms: int, classes: int, groups=(1, 8, 64),
               repeats: int = 5, wave_batch: int = 256,
-              wave_repeats: int = 10, seed: int = 47,
-              cold_start: bool = True) -> dict:
-    """The PR 10 measurements, three blocks:
+              wave_repeats: int = 10, seed: int = 47) -> dict:
+    """The PR 10 measurements, two blocks:
 
     * ``planner`` — the fully on-device plane (``sur_greedy_many``: greedy-
       on-gamma, l*, and candidate scoring fused into the scan program) vs
@@ -1055,9 +994,7 @@ def raw_speed(num_arms: int, classes: int, groups=(1, 8, 64),
       (``donate_buffers=True``, the default) vs the nodonate twin, outputs
       bit-checked (donation is a storage contract, not a numerics knob; on
       backends where the reduction outputs can't alias the staged tables
-      the timing delta is expected to be noise);
-    * ``cold_start`` — cold-*process* first-plan latency twice against one
-      shared ``REPRO_COMPILE_CACHE_DIR``, second process cache-warmed.
+      the timing delta is expected to be noise).
 
     All timed loops run strictly after per-bucket warm-ups; a local
     CompileSentinel records ``timed_recompiles`` for the section.
@@ -1171,22 +1108,6 @@ def raw_speed(num_arms: int, classes: int, groups=(1, 8, 64),
     )
     timed_recompiles += sentinel.total()
 
-    # -- cold-start replan latency vs the persistent compile cache -------
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cold = _cold_start_cache(repo_root) if cold_start else {
-        "skipped": True, "reason": "disabled"
-    }
-    if cold.get("skipped"):
-        print(f"raw speed cold-start: skipped ({cold.get('reason')})")
-    else:
-        print(
-            f"raw speed cold-start: first {cold['first_plan_s']:6.2f}s | "
-            f"cache-warmed {cold['second_plan_s']:6.2f}s "
-            f"({cold['speedup']:.2f}x, {cold['cache_entries']} cache entries)"
-        )
-
-    from repro.kernels.ops import kernel_compile_probe
-
     return {
         "planner": {
             "rows": plan_rows,
@@ -1196,8 +1117,6 @@ def raw_speed(num_arms: int, classes: int, groups=(1, 8, 64),
             "theta": theta,
         },
         "donation": donation,
-        "cold_start": cold,
-        "kernel_compile": kernel_compile_probe(),
         "timed_recompiles": int(timed_recompiles),
     }
 
@@ -1359,13 +1278,12 @@ def run(args) -> dict:
     )
 
     # raw-speed pass: fused on-device planner vs PR 9 host-gamma plane,
-    # donated vs non-donated wave dispatch, cold-start compile cache
+    # donated vs non-donated wave dispatch
     raw = raw_speed(
         args.arms, args.classes,
         repeats=args.raw_repeats,
         wave_batch=min(256, max(batches)),
         wave_repeats=max(4, args.repeats // 2),
-        cold_start=not args.no_cold_start,
     )
     print(
         f"raw speed: planner {raw['planner']['speedup_at_max']:.2f}x fused "
@@ -1564,9 +1482,6 @@ def _load_history(path: str) -> list:
             entry["raw_speed"]["nodonate_over_donate"] = donation.get(
                 "nodonate_over_donate"
             )
-        cold = raw.get("cold_start", {})
-        if cold and not cold.get("skipped"):
-            entry["raw_speed"]["cold_start_speedup"] = cold.get("speedup")
     fault = prev.get("fault_tolerance")
     if fault:
         entry["fault_tolerance"] = {
@@ -1628,10 +1543,6 @@ def main() -> None:
         help="best-of rounds for the raw-speed planner timings",
     )
     ap.add_argument(
-        "--no-cold-start", action="store_true",
-        help="skip the two-subprocess persistent-compile-cache measurement",
-    )
-    ap.add_argument(
         "--smoke", action="store_true",
         help="tiny sweep for CI: small batches, few repeats",
     )
@@ -1650,6 +1561,7 @@ def main() -> None:
         args.selection_history = min(args.selection_history, 60)
         args.selection_repeats = min(args.selection_repeats, 2)
         args.raw_repeats = min(args.raw_repeats, 2)
+    configure_compile_cache()
     run(args)
 
 

@@ -12,8 +12,7 @@
 # exercised — (one-shot engines + the steady-state continuous-batching
 # path + the online feedback-vs-drift section + the fault-tolerance
 # section + the replica-scaling sweep + the cross_device subsection + the
-# raw-speed section with its two-subprocess persistent-compile-cache
-# cold-start gate + the compile-sentinel budget) with JSON well-formedness and
+# raw-speed section + the compile-sentinel budget) with JSON well-formedness and
 # history-preservation assertions, a docs link check plus a docs symbol
 # check (every doc-mentioned repro.* identifier must resolve against the
 # tree), then the FULL tier-1
@@ -43,8 +42,8 @@ python -m pytest -x -q tests/test_router_batched.py tests/test_serving.py \
     tests/test_selection_batched.py tests/test_failover.py \
     tests/test_replica.py
 
-# multi-device replica placement: force 4 host CPU devices (the same knob
-# `repro.launch.serve --devices` uses) so the overlapped placement path is
+# multi-device replica placement: force 4 host CPU devices (XLA_FLAGS, set
+# before JAX starts) so the overlapped placement path is
 # real, not the single-device fallback. These tests skip themselves below
 # 2 devices — a skip here means the forcing flag stopped working; fail
 # loudly instead of silently testing nothing.
@@ -147,15 +146,12 @@ assert sel["speedup_at_max"] > 0, "replan timing is malformed"
 
 # the raw-speed section (PR 10): fused on-device planner vs the PR 9
 # host-gamma plane with bit-identical plans, donated vs non-donated wave
-# dispatch bit-checked, the two-subprocess cold-start measurement against
-# a shared persistent compile-cache dir (skip-gated with an honesty
-# reason when the backend lacks cache support), the kernel-compile honesty
-# probe, and zero recompiles inside the section's timed loops. The
+# dispatch bit-checked, and zero recompiles inside the section's timed
+# loops. The
 # >= 1.3x planner bar at G = 64 lives in the committed full-size report;
 # wall-clock bars at smoke scale would make CI flaky on loaded hosts.
 raw = report["raw_speed"]
-for key in ("planner", "donation", "cold_start", "kernel_compile",
-            "timed_recompiles"):
+for key in ("planner", "donation", "timed_recompiles"):
     assert key in raw, f"raw_speed missing {key}"
 pl = raw["planner"]
 assert pl["rows"], "raw_speed planner has no rows"
@@ -168,21 +164,6 @@ assert pl["groups_max"] >= 8, "raw_speed planner never measured multi-group"
 dn = raw["donation"]
 assert dn["bit_identical"], "donated wave dispatch diverged from nodonate"
 assert dn["donate_s"] > 0 and dn["nodonate_s"] > 0, "bad donation timing"
-cold = raw["cold_start"]
-if cold.get("skipped"):
-    assert cold.get("reason"), "cold_start skipped without an honesty reason"
-    print(f"cold-start cache stage skipped: {cold['reason']}")
-else:
-    assert cold["cache_entries"] > 0, "cache-warmed run left no cache entries"
-    assert cold["improved"], (
-        f"persistent compile cache did not improve the second cold process: "
-        f"first {cold['first_plan_s']:.2f}s, second {cold['second_plan_s']:.2f}s")
-kc = raw["kernel_compile"]
-assert "backend" in kc and "kernels" in kc, "kernel_compile probe malformed"
-for kname, entry in kc["kernels"].items():
-    assert "compiled" in entry, f"kernel probe entry malformed: {kname}"
-    if not entry["compiled"]:
-        assert entry.get("error"), f"uncompiled kernel {kname} with no reason"
 assert raw["timed_recompiles"] == 0, \
     f"recompiles inside raw_speed timed loops: {raw['timed_recompiles']}"
 
@@ -306,9 +287,7 @@ print("serving smoke OK:", [(r["batch"], round(r["qps"])) for r in report["rows"
       f"| fault recovery {ft['replan_recovery']:.2f} (frozen {ft['frozen_recovery']:.2f})",
       f"| batched replan {sel['speedup_at_max']:.2f}x at G={sel['groups_max']}",
       f"| raw planner {pl['speedup_at_max']:.2f}x at G={pl['groups_max']}"
-      f" (plans match {pl['plans_match']}, donation bit-id {dn['bit_identical']},"
-      f" cold-start " + ("skipped" if cold.get("skipped")
-                         else f"{cold['speedup']:.1f}x") + ")",
+      f" (plans match {pl['plans_match']}, donation bit-id {dn['bit_identical']})",
       f"| replicas {rs['speedup_at_max']:.2f}x at R={rs['replicas_max']}"
       f" (R=1 bitmatch {rs['r1_bitmatch_steady']})",
       f"| compiles wave {cs['wave_compiles']}/{cs['wave_bucket_budget']}"

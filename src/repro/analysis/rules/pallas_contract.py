@@ -13,9 +13,9 @@ designed around:
   the ``flash_attention`` closure idiom).  A mismatch is a runtime error
   only on the first *compiled* run, which CPU-interpret CI never takes.
 * **interpret plumbing** — every ``pl.pallas_call`` must thread an
-  ``interpret=`` flag from a parameter or module switch; omitting it (or
-  hard-coding a bool) strands the kernel on one backend and breaks the
-  ``REPRO_KERNEL_COMPILE`` toggle.
+  ``interpret=`` flag from a parameter; omitting it (or hard-coding a
+  bool) strands the kernel on one backend, out of reach of the platform
+  choice in ``repro.kernels.ops`` (interpret on CPU, native on TPU).
 """
 from __future__ import annotations
 
@@ -115,8 +115,8 @@ def check(project: Project) -> list[Finding]:
                     line=interp.lineno,
                     symbol=sym,
                     message=f"pallas_call hard-codes interpret="
-                    f"{interp.value}: the REPRO_KERNEL_COMPILE toggle "
-                    "cannot reach this kernel",
+                    f"{interp.value}: the platform choice in "
+                    "repro.kernels.ops cannot reach this kernel",
                 )
             )
 

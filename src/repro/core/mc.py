@@ -30,7 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from .x64 import x64
 
 from .belief import empty_log_belief, log_weight
 from .types import clip_probs
@@ -484,7 +484,7 @@ class GroupedXiEstimator:
                     rows = np.flatnonzero(col >= 0)
                     raw[g, c, rows, col[rows]] += self.log_weights[g, l]
                     cnt[g, c, rows, col[rows]] += 1
-        with enable_x64():
+        with x64():
             vals = xi_from_tables_grouped(
                 raw, cnt, self.empty, self.valid, self.theta_f,
                 num_classes=K,
@@ -493,7 +493,7 @@ class GroupedXiEstimator:
 
     def marginal(self, base_raw: np.ndarray, base_cnt: np.ndarray) -> np.ndarray:
         """(G, T, K) current-set belief tables -> (G, L) xi of set ∪ {l}."""
-        with enable_x64():
+        with x64():
             vals = xi_marginal_grouped(
                 self.responses_t, np.asarray(base_raw, np.float32),
                 np.asarray(base_cnt, np.int32), self.log_weights, self.empty,
@@ -551,7 +551,7 @@ class GroupedXiEstimator:
         for g in range(G):
             self._accumulate(raw[g, 0], cnt[g, 0], g, [int(l_stars[g])])
             self._accumulate(raw[g, 2], cnt[g, 2], g, sorted(int(a) for a in s2s[g]))
-        with enable_x64():
+        with x64():
             vals = xi_from_tables_grouped(
                 raw, cnt, self.empty, self.valid, self.theta_f,
                 num_classes=K,

@@ -28,7 +28,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from .x64 import x64
 
 from .belief import (
     aggregate_log_beliefs,
@@ -313,7 +313,7 @@ def _sur_greedy_scan_core(
        the device.
 
     Groups whose affordable set empties freeze in place; padded groups
-    (budget < 0) never pick and stay inert. Runs under ``enable_x64``.
+    (budget < 0) never pick and stay inert. Runs under ``repro.core.x64.x64``.
 
     With ``full=False`` only phase 1 runs and the return is the PR 9
     planner surface ``(picks, npick, value, spent, base_raw, base_cnt)``
@@ -672,7 +672,7 @@ def sur_greedy_many(
     )
     scr, b_p, n, _ = _stage_groups(est, b, budgets[live], group_bucket)
     scan_fn = _sur_greedy_scan if donate else _sur_greedy_scan_nodonate
-    with enable_x64(), _quiet_donation():
+    with x64(), _quiet_donation():
         out = scan_fn(
             scr["resp"], scr["valid"], scr["w"], scr["empty"], scr["theta"],
             scr["p"], b_p, scr["budgets"], scr["m"],
@@ -742,7 +742,7 @@ def _sur_greedy_many_hostgamma(
     budgets_p[:n] = budgets[live]
     m_p = np.exp(np.log1p(-p_p))
 
-    with enable_x64():
+    with x64():
         picks, npick, _, _, s1_raw, s1_cnt = _sur_greedy_scan_nodonate(
             resp_p, valid_p, w_p, empty_p, theta_p, p_p, b_p, budgets_p,
             m_p, num_classes=K, full=False,

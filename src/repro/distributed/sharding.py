@@ -20,6 +20,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
+
 LogicalAxes = Union[None, str, Tuple[str, ...]]
 
 # Default logical -> mesh-axis mapping. "fsdp" shards parameter rows over the
@@ -283,4 +285,4 @@ def replica_mesh(replicas: int) -> Optional[Mesh]:
     n = min(int(replicas), len(devs))
     if n <= 1:
         return None
-    return Mesh(np.asarray(devs[:n]), axis_names=("replica",))
+    return make_mesh((n,), ("replica",), devices=devs[:n])

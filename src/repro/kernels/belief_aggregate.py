@@ -5,9 +5,11 @@ beliefs (paper Eq. 4) and the argmax prediction:
 
     beliefs[b, k] = sum_m w[b, m] * onehot(resp[b, m])[k]   (empty -> const)
 
-Grid over request tiles; the (Bt, M, K) one-hot cube lives in VMEM and the
-contraction over M is an MXU batched dot. Arms flagged -1 are masked (not
-invoked for that request — adaptive early-stopped wavefronts).
+Grid over request tiles. The contraction over the M arms is an unrolled
+multiply-and-sum of (Bt, K) slabs, in ascending arm order: every operand is
+2-D, which the TPU compiler needs, and the float32 sum order does not depend
+on which other arms are masked. Arms flagged -1 are masked (not invoked for
+that request — adaptive early-stopped wavefronts).
 """
 from __future__ import annotations
 
@@ -23,19 +25,22 @@ def _kernel(resp_ref, w_ref, empty_ref, bel_ref, pred_ref, *, num_classes):
     Bt, M = resp.shape
     K = num_classes
     w = w_ref[...]                                          # (Bt, M)
-    valid = (resp >= 0).astype(jnp.float32)
 
-    classes = jax.lax.broadcasted_iota(jnp.int32, (Bt, M, K), 2)
-    onehot = (resp[:, :, None] == classes).astype(jnp.float32)
-
-    beliefs = jnp.einsum("bm,bmk->bk", w * valid, onehot,
-                         preferred_element_type=jnp.float32)
-    counts = jnp.einsum("bm,bmk->bk", valid, onehot,
-                        preferred_element_type=jnp.float32)
+    classes = jax.lax.broadcasted_iota(jnp.int32, (Bt, K), 1)
+    beliefs = jnp.zeros((Bt, K), jnp.float32)
+    seen = jnp.zeros((Bt, K), jnp.bool_)
+    for m in range(M):                   # -1 responses match no class
+        hit = resp[:, m:m + 1] == classes                   # (Bt, K)
+        beliefs = beliefs + jnp.where(hit, w[:, m:m + 1], 0.0)
+        seen = seen | hit
     empty = empty_ref[...]                                  # (Bt, 1) per-row
-    beliefs = jnp.where(counts > 0, beliefs, empty)
+    beliefs = jnp.where(seen, beliefs, empty)
     bel_ref[...] = beliefs
-    pred_ref[...] = jnp.argmax(beliefs, axis=-1).astype(jnp.int32)[:, None]
+    # first-max argmax as a max and a min, both plain lane reductions
+    mx = jnp.max(beliefs, axis=-1, keepdims=True)
+    pred_ref[...] = jnp.min(
+        jnp.where(beliefs == mx, classes, K), axis=-1, keepdims=True
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("num_classes", "tile", "interpret"))

@@ -122,50 +122,52 @@ def mc_correctness_pallas(
 
 def _grouped_kernel(resp_ref, maskw_ref, mask_ref, empty_ref, valid_ref,
                     theta_ref, out_ref, *, num_classes):
-    """One (group, theta-tile) cell.
+    """One (group, theta-tile) cell. Every block's last two dims are the
+    array's own (or 8x128-aligned), as the TPU compiler requires.
 
     resp_ref:  (1, Tt, L) int32 responses of the cell's group
     maskw_ref: (1, C, L) f32 mask * log-weight
     mask_ref:  (1, C, L) f32 subset indicator
-    empty_ref: (1, 1) f32 empty-class log belief
-    valid_ref: (1, Tt) f32 draw mask (0 past the group's own theta)
-    theta_ref: (1, 1) f32 the group's real draw count
-    out_ref:   (1, C) f32 accumulated xi estimates (revisited over tiles)
+    empty_ref: (1, 1, 1) f32 empty-class log belief
+    valid_ref: (1, 1, Tt) f32 draw mask (0 past the group's own theta)
+    theta_ref: (1, 1, 1) f32 the group's real draw count
+    out_ref:   (1, C, 1) f32 accumulated xi estimates (revisited over tiles)
     """
     i = pl.program_id(1)
 
     resp = resp_ref[0]                                     # (Tt, L)
-    Tt, L = resp.shape
-    K = num_classes
-
-    classes = jax.lax.broadcasted_iota(jnp.int32, (Tt, L, K), 2)
-    onehot = (resp[:, :, None] == classes).astype(jnp.float32)
-
     maskw = maskw_ref[0]                                   # (C, L)
     mask = mask_ref[0]
-    flat = onehot.transpose(1, 0, 2).reshape(L, Tt * K)    # (L, Tt*K)
-    dn = (((1,), (0,)), ((), ()))
-    beliefs = jax.lax.dot_general(
-        maskw, flat, dn, preferred_element_type=jnp.float32
-    ).reshape(-1, Tt, K)
-    counts = jax.lax.dot_general(
-        mask, flat, dn, preferred_element_type=jnp.float32
-    ).reshape(-1, Tt, K)
+    empty = empty_ref[0]                                   # (1, 1)
 
-    empty = empty_ref[0, 0]
-    beliefs = jnp.where(counts > 0, beliefs, empty)
+    # per class k: (C, L) x (Tt, L)^T -> (C, Tt) beliefs on the MXU, at
+    # full float32 precision (the one-hots are exact; the weights are not)
+    dn = (((1,), (1,)), ((), ()))
+    hp = jax.lax.Precision.HIGHEST
+    disp = []
+    for k in range(num_classes):
+        onehot = (resp == k).astype(jnp.float32)           # (Tt, L)
+        bel = jax.lax.dot_general(maskw, onehot, dn, precision=hp,
+                                  preferred_element_type=jnp.float32)
+        cnt = jax.lax.dot_general(mask, onehot, dn, precision=hp,
+                                  preferred_element_type=jnp.float32)
+        disp.append(jnp.where(cnt > 0, bel, empty))
 
-    mx = jnp.max(beliefs, axis=-1, keepdims=True)
-    is_max = (beliefs >= mx - TIE_TOL).astype(jnp.float32)
-    ties = jnp.sum(is_max, axis=-1)                        # (C, Tt)
-    credit = is_max[:, :, 0] / ties * valid_ref[0][None, :]
-    partial = jnp.sum(credit, axis=-1) / theta_ref[0, 0]   # (C,)
+    mx = disp[0]
+    for d in disp[1:]:
+        mx = jnp.maximum(mx, d)
+    thr = mx - TIE_TOL
+    ties = jnp.zeros_like(mx)
+    for d in disp:
+        ties = ties + (d >= thr).astype(jnp.float32)
+    credit = (disp[0] >= thr).astype(jnp.float32) / ties * valid_ref[0]
+    partial = jnp.sum(credit, axis=-1, keepdims=True) / theta_ref[0]  # (C, 1)
 
     @pl.when(i == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[0, :] += partial
+    out_ref[0] += partial
 
 
 @functools.partial(
@@ -187,7 +189,9 @@ def mc_correctness_grouped_pallas(
     draws carry ``valid`` 0 and contribute nothing, so no post-hoc padding
     correction is needed (unlike the ungrouped kernel). Same flag
     conventions as ``belief_aggregate``: ``interpret`` on CPU, ``tile``
-    trades grid steps for VMEM with no effect on results."""
+    trades grid steps for VMEM with no effect on results. The per-group
+    operands ride 3-D layouts so that each block's last two dims equal the
+    array's."""
     G, theta_n, L = responses.shape
     C = masks.shape[1]
     tile = min(tile, theta_n)
@@ -201,21 +205,23 @@ def mc_correctness_grouped_pallas(
             [valid, jnp.zeros((G, pad), jnp.float32)], axis=1
         )
     maskw = masks * log_weights[:, None, :]
-    empty = jnp.asarray(empty_belief, jnp.float32).reshape(G, 1)
-    theta = jnp.asarray(theta, jnp.float32).reshape(G, 1)
+    empty = jnp.asarray(empty_belief, jnp.float32).reshape(G, 1, 1)
+    theta = jnp.asarray(theta, jnp.float32).reshape(G, 1, 1)
+    valid = jnp.asarray(valid, jnp.float32).reshape(G, 1, n * tile)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_grouped_kernel, num_classes=num_classes),
         grid=(G, n),
         in_specs=[
             pl.BlockSpec((1, tile, L), lambda g, i: (g, i, 0)),
             pl.BlockSpec((1, C, L), lambda g, i: (g, 0, 0)),
             pl.BlockSpec((1, C, L), lambda g, i: (g, 0, 0)),
-            pl.BlockSpec((1, 1), lambda g, i: (g, 0)),
-            pl.BlockSpec((1, tile), lambda g, i: (g, i)),
-            pl.BlockSpec((1, 1), lambda g, i: (g, 0)),
+            pl.BlockSpec((1, 1, 1), lambda g, i: (g, 0, 0)),
+            pl.BlockSpec((1, 1, tile), lambda g, i: (g, 0, i)),
+            pl.BlockSpec((1, 1, 1), lambda g, i: (g, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, C), lambda g, i: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, C), jnp.float32),
+        out_specs=pl.BlockSpec((1, C, 1), lambda g, i: (g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((G, C, 1), jnp.float32),
         interpret=interpret,
     )(responses, maskw, masks, empty, valid, theta)
+    return out[:, :, 0]

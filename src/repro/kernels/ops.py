@@ -1,14 +1,13 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in ``interpret=True`` mode (the kernel
-body executes in Python per grid step) — set ``REPRO_KERNEL_COMPILE=1`` on a
-real TPU to lower them natively. The wrappers handle padding/layout so call
-sites never see tiling constraints.
+The platform decides how a kernel runs: on a CPU backend it runs in
+``interpret=True`` mode (the kernel body executes per grid step as plain
+JAX), on a TPU it is lowered natively, never interpreted. The wrappers
+handle padding/layout so call sites never see tiling constraints.
 """
 from __future__ import annotations
 
-import os
-
+import jax
 import jax.numpy as jnp
 
 from .belief_aggregate import belief_aggregate_pallas
@@ -16,14 +15,17 @@ from .flash_attention import flash_attention_pallas
 from .mc_correctness import mc_correctness_grouped_pallas, mc_correctness_pallas
 from .rglru_scan import rglru_scan_pallas
 
-_INTERPRET = os.environ.get("REPRO_KERNEL_COMPILE", "0") != "1"
+
+def _interpret() -> bool:
+    """Pallas has no CPU lowering: interpret there, and only there."""
+    return jax.default_backend() == "cpu"
 
 
 def mc_correctness(responses, masks, log_weights, empty_belief, num_classes: int):
     """(C,) Monte-Carlo xi estimates over shared response draws."""
     return mc_correctness_pallas(
         responses, masks, log_weights, empty_belief, num_classes,
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )
 
 
@@ -33,7 +35,7 @@ def mc_correctness_grouped(responses, masks, log_weights, empty_belief,
     draws; ragged thetas carried by the ``valid`` mask."""
     return mc_correctness_grouped_pallas(
         responses, masks, log_weights, empty_belief, valid, theta,
-        num_classes, tile=tile, interpret=_INTERPRET,
+        num_classes, tile=tile, interpret=_interpret(),
     )
 
 
@@ -47,7 +49,7 @@ def belief_aggregate(responses, log_weights, empty_belief, num_classes: int,
     """
     return belief_aggregate_pallas(
         responses, log_weights, empty_belief, num_classes, tile=tile,
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )
 
 
@@ -56,7 +58,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
     """(B,S,H,hd) x (B,T,G,hd) -> (B,S,H,hd) with causal block skipping."""
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window,
-        block_q=block_q, block_kv=block_kv, interpret=_INTERPRET,
+        block_q=block_q, block_kv=block_kv, interpret=_interpret(),
     )
 
 
@@ -66,7 +68,7 @@ def rglru_scan(log_a, gated, h0):
         jnp.asarray(log_a, jnp.float32),
         jnp.asarray(gated, jnp.float32),
         jnp.asarray(h0, jnp.float32),
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )
 
 
@@ -77,66 +79,5 @@ def mamba_scan(x, dt, A, Bmat, Cmat, Dskip, h0):
     f32 = lambda t: jnp.asarray(t, jnp.float32)
     return mamba_scan_pallas(
         f32(x), f32(dt), f32(A), f32(Bmat), f32(Cmat), f32(Dskip), f32(h0),
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )
-
-
-def kernel_compile_probe() -> dict:
-    """Attempt *native* (``interpret=False``) compilation of the serving
-    kernels and report what actually happened — the honesty record behind
-    ``REPRO_KERNEL_COMPILE=1``.
-
-    Tries ``belief_aggregate`` and ``mc_correctness_grouped`` on tiny
-    inputs with interpretation forced off, regardless of the env var, and
-    captures the per-kernel outcome::
-
-        {"backend": str, "interpret_default": bool,
-         "kernels": {name: {"compiled": bool, "error": str}}}
-
-    Known result on this CPU container (documented Mosaic/Triton gap):
-    both kernels raise ``ValueError: Only interpret mode is supported on
-    CPU backend.`` — Pallas has no CPU lowering path, so native-kernel
-    validation requires a real TPU (Mosaic) or GPU (Triton) runtime.
-    """
-    import jax
-    import numpy as np
-
-    K = 2
-    out: dict = {
-        "backend": jax.default_backend(),
-        "interpret_default": _INTERPRET,
-        "kernels": {},
-    }
-
-    def attempt(name, fn):
-        try:
-            res = fn()
-            jax.block_until_ready(res)
-            out["kernels"][name] = {"compiled": True, "error": ""}
-        except Exception as exc:
-            out["kernels"][name] = {
-                "compiled": False, "error": f"{type(exc).__name__}: {exc}"
-            }
-
-    attempt(
-        "belief_aggregate",
-        lambda: belief_aggregate_pallas(
-            jnp.zeros((2, 2), jnp.int32),
-            jnp.zeros((2, 2), jnp.float32),
-            jnp.zeros(2, jnp.float32),
-            K, tile=2, interpret=False,
-        ),
-    )
-    attempt(
-        "mc_correctness_grouped",
-        lambda: mc_correctness_grouped_pallas(
-            jnp.zeros((1, 2, 2), jnp.int32),
-            jnp.zeros((1, 1, 2), jnp.float32),
-            jnp.zeros((1, 2), jnp.float32),
-            jnp.zeros(1, jnp.float32),
-            jnp.asarray(np.ones((1, 2), np.float32)),
-            jnp.ones(1, jnp.float32),
-            K, tile=2, interpret=False,
-        ),
-    )
-    return out

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from jax.experimental import enable_x64
+from repro.core.x64 import x64
 
 from repro.core.mc import xi_from_responses, xi_from_responses_grouped
 from repro.core.belief import aggregate_log_beliefs_batch
@@ -22,7 +22,7 @@ def mc_correctness_grouped_ref(responses, masks, log_weights, empty_belief,
                                valid, theta, num_classes):
     """(G, C) xi estimates — delegates to the batched planner's bit-stable
     grouped core (f64 out; compare with a float32 tolerance)."""
-    with enable_x64():
+    with x64():
         vals = xi_from_responses_grouped(
             responses, masks, log_weights, empty_belief, valid,
             jnp.asarray(theta, jnp.float64), num_classes=num_classes,

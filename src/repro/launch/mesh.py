@@ -7,19 +7,33 @@ import to obtain placeholder devices.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None):
+    """Every mesh in the repo is built here, with Auto axes: the model code
+    shards through ``with_sharding_constraint`` and leaves the rest to the
+    partitioner, which Explicit axes (``jax.make_mesh``'s default) refuse."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256-chip single pod, or 2x16x16 = 512-chip two-pod mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh for CPU integration tests (requires forced host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e-class hardware constants for the roofline analysis.

@@ -38,28 +38,15 @@ _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Version-proof reader for ``compiled.cost_analysis()``.
+    """``compiled.cost_analysis()`` as a flat {counter: float} mapping.
 
-    Across jaxlib releases this API has returned a flat dict of counters, a
-    *list* of per-computation dicts (so ``cost_analysis()["flops"]`` raises
-    ``TypeError: list indices must be integers``), or None. Normalize to one
-    flat {counter: float} mapping: dicts pass through, list entries are
-    summed key-wise (the common single-entry list is therefore a
-    passthrough too). Every read of ``cost_analysis()`` in this repo must go
-    through this shim.
+    The numeric counters of XLA's dict; empty where the backend has no
+    cost analysis (JAX then returns None). Every read of
+    ``cost_analysis()`` in this repo goes through here.
     """
-    analysis = compiled.cost_analysis()
-    if analysis is None:
-        return {}
-    if isinstance(analysis, dict):
-        return {k: float(v) for k, v in analysis.items()
-                if isinstance(v, (int, float))}
-    out: Dict[str, float] = {}
-    for entry in analysis:
-        for k, v in dict(entry).items():
-            if isinstance(v, (int, float)):
-                out[k] = out.get(k, 0.0) + float(v)
-    return out
+    analysis = compiled.cost_analysis() or {}
+    return {k: float(v) for k, v in analysis.items()
+            if isinstance(v, (int, float))}
 
 
 def _shape_bytes(type_str: str) -> int:

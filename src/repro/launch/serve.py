@@ -29,11 +29,14 @@ estimator so flaky arms replan away (combine with ``--drift-after`` or
         --drift-after 500 --probe-rate 0.05
     PYTHONPATH=src python -m repro.launch.serve --queries 2000 \
         --probe-rate 0.05 --fault-rate 0.3 --fault-arms 0,1
+
+``--replicas R`` places the replicas on the process's devices: the chips
+of a TPU host, or on a CPU host as many devices as
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` forces.
 """
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import numpy as np
@@ -53,27 +56,43 @@ from repro.serving import (
 )
 
 
+# The launcher's own deployment; ``chip_smoke.py`` serves the same one.
+DEPLOYMENT = {"arms": 12, "classes": 4, "clusters": 6, "history": 2000}
+BUDGET = 1e-4
+MAX_BATCH = 64
+
+
+def build_deployment(*, arms: int, classes: int, clusters: int,
+                     history: int, metered: bool = False):
+    """The oracle pool calibrated and clustered on ``history`` historical
+    queries. Returns ``(workload, router)``."""
+    wl = OracleWorkload(
+        num_classes=classes, num_clusters=clusters, num_arms=arms
+    )
+    engine = PoolEngine(
+        [OracleArm(f"llm-{i}", wl, i, metered=metered) for i in range(arms)]
+    )
+    T, emb, _ = wl.response_table(history)
+    assign, _ = kmeans(emb, clusters, seed=0)
+    est = SuccessProbEstimator(T, emb, assign)
+    return wl, ThriftRouter(engine, est, num_classes=classes)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arms", type=int, default=12)
-    ap.add_argument("--classes", type=int, default=4)
-    ap.add_argument("--clusters", type=int, default=6)
+    ap.add_argument("--arms", type=int, default=DEPLOYMENT["arms"])
+    ap.add_argument("--classes", type=int, default=DEPLOYMENT["classes"])
+    ap.add_argument("--clusters", type=int, default=DEPLOYMENT["clusters"])
     ap.add_argument("--queries", type=int, default=500)
-    ap.add_argument("--budget", type=float, default=1e-4)
-    ap.add_argument("--history", type=int, default=2000)
-    ap.add_argument("--max-batch", type=int, default=64)
+    ap.add_argument("--budget", type=float, default=BUDGET)
+    ap.add_argument("--history", type=int, default=DEPLOYMENT["history"])
+    ap.add_argument("--max-batch", type=int, default=MAX_BATCH)
     ap.add_argument("--max-wait-ms", type=float, default=2.0)
     ap.add_argument("--replicas", type=int, default=1,
                     help="serve through an R-replica ReplicaSet (sharded "
                          "admission, per-device overlapped or fused waves, "
                          "shard-merged feedback); 1 = the plain "
                          "BatchScheduler path")
-    ap.add_argument("--devices", type=int, default=0,
-                    help="force this many host (CPU) XLA devices so the "
-                         "replica plane can overlap per-device wave "
-                         "programs; 0 = whatever the process already has. "
-                         "Must take effect before JAX initializes its "
-                         "backend, so it is applied at the top of main()")
     ap.add_argument("--placement", type=str, default="auto",
                     choices=["auto", "overlapped", "fused", "inline"],
                     help="replica wave placement (auto: overlapped when "
@@ -99,43 +118,19 @@ def main() -> None:
     ap.add_argument("--fault-arms", type=str, default="",
                     help="comma-separated arm indices the fault policy "
                          "targets (default: all arms)")
-    ap.add_argument("--compile-cache-dir", type=str, default=None,
-                    help="persist XLA executables to this directory so a "
-                         "restarted process loads its wave/planner compile "
-                         "buckets from disk instead of re-lowering "
-                         "(default: $REPRO_COMPILE_CACHE_DIR, else off)")
     args = ap.parse_args()
+    configure_compile_cache()
 
-    cache_info = configure_compile_cache(args.compile_cache_dir)
-    if cache_info["cache_dir"] is not None:
-        print(
-            f"compile cache: enabled={cache_info['enabled']} "
-            f"dir={cache_info['cache_dir']} backend={cache_info['backend']} "
-            f"supported={cache_info['supported']} — {cache_info['detail']}"
-        )
-
-    if args.devices > 0:
-        # must land before the first backend touch (jax.devices() inside
-        # ReplicaSet); module imports alone don't initialize the backend
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count="
-            f"{args.devices}"
-        ).strip()
-
-    wl = OracleWorkload(
-        num_classes=args.classes, num_clusters=args.clusters, num_arms=args.arms
-    )
-    engine = PoolEngine(
-        [OracleArm(f"llm-{i}", wl, i, metered=args.metered)
-         for i in range(args.arms)]
+    wl, router = build_deployment(
+        arms=args.arms, classes=args.classes, clusters=args.clusters,
+        history=args.history, metered=args.metered,
     )
     if args.fault_rate > 0:
         targets = (
             [int(a) for a in args.fault_arms.split(",") if a.strip()]
             if args.fault_arms else list(range(args.arms))
         )
-        engine.fault_policy = FaultPolicy(
+        router.engine.fault_policy = FaultPolicy(
             args.arms, args.classes, seed=7
         ).set_arms(
             targets,
@@ -143,13 +138,9 @@ def main() -> None:
             error=0.3 * args.fault_rate,
             degrade=0.2 * args.fault_rate,
         )
-    T, emb, _ = wl.response_table(args.history)
-    assign, _ = kmeans(emb, args.clusters, seed=0)
-    est = SuccessProbEstimator(T, emb, assign)
-    router = ThriftRouter(engine, est, num_classes=args.classes)
     online = args.drift_after > 0 or args.probe_rate > 0
     feedback = (
-        FeedbackLog(est, probe_rate=args.probe_rate) if online else None
+        FeedbackLog(router.estimator, probe_rate=args.probe_rate) if online else None
     )
     if args.replicas > 1:
         sched = ReplicaSet(

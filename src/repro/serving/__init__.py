@@ -2,7 +2,7 @@
 online estimation feedback, fault injection + degradation tracking."""
 from repro.distributed.fault import ArmFaultSpec, FaultPolicy
 
-from .compile_cache import cache_supported, configure_compile_cache
+from .compile_cache import configure_compile_cache
 from .engine import LMArm, OracleArm, PoolEngine, USD_PER_FLOP
 from .feedback import (
     DegradationTracker,
@@ -33,5 +33,5 @@ __all__ = [
     "BlockFuture", "CostLedger",
     "ReplicaSet", "ReplicaWorker",
     "ArmFaultSpec", "FaultPolicy",
-    "configure_compile_cache", "cache_supported",
+    "configure_compile_cache",
 ]

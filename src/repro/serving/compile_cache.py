@@ -1,114 +1,27 @@
-"""Persistent XLA compilation-cache plumbing for cold starts.
+"""Where compiled programs persist between processes.
 
-Compile-bucket warmth normally dies with the process: every restart pays
-the full lowering + XLA compile cost for each `_wave_scan` / planner
-bucket again before serving is fast (PR 9 made the cost ledger the last
-*state* to survive restarts; the compile cache was the last *latency*).
-JAX ships a persistent on-disk compilation cache — executables keyed by
-(HLO, jaxlib, backend) — which turns a warm restart's compiles into disk
-loads.
-
-Opt-in, off by default: set ``REPRO_COMPILE_CACHE_DIR=/path`` (or pass
-``cache_dir``) and every jit compile triggered afterwards — including the
-prewarm loops in :meth:`ThriftRouter.prewarm_compile` /
-:meth:`ReplicaSet.prewarm_compile` — reads through / writes to that
-directory. The thresholds are pinned so *all* entries persist (JAX's
-defaults skip programs that compile in under a second, which is exactly
-the regime of the serving buckets on CPU).
-
-Honesty fields: :func:`configure_compile_cache` returns what actually
-happened (enabled, directory, backend, whether the backend supports the
-cache, and a detail string) rather than assuming support — mirroring the
-``parallel_capable`` pattern from the cross-device bench. Known gap
-recorded by :func:`repro.kernels.ops.kernel_compile_probe`: the Pallas
-kernels cannot lower natively on the CPU backend (interpret mode only),
-so ``REPRO_KERNEL_COMPILE=1`` validation needs a real TPU/GPU — the probe
-documents the exact error per kernel.
+JAX's persistent compilation cache turns a restarted process's compiles
+into disk loads. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+itself and this module sets nothing. Otherwise the entry points
+(``repro.launch.serve``, the serving benchmark's ``main`` and
+``chip_smoke.py``) call :func:`configure_compile_cache` before their first
+compile, and the cache lives at ``<repo>/.jax_cache``: a fixed path, so a
+later process finds what an earlier one wrote. Library code (the prewarm
+seams) never sets a cache.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
+import pathlib
 
 import jax
 
-ENV_VAR = "REPRO_COMPILE_CACHE_DIR"
-
-# jax config keys -> pinned values: persist every entry, however small or
-# fast-compiling (the serving buckets are sub-second compiles on CPU).
-_CACHE_KEYS = (
-    ("jax_persistent_cache_min_compile_time_secs", 0.0),
-    ("jax_persistent_cache_min_entry_size_bytes", -1),
-)
-
-_state: dict = {"dir": None, "info": None}   # idempotence memo
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def cache_supported() -> bool:
-    """Best-effort probe: does this jax/backend pair implement the
-    persistent compilation cache? CPU/GPU/TPU backends do on the pinned
-    jax; interpret-mode Pallas and exotic plugin backends may not."""
-    try:
-        from jax._src import compilation_cache  # noqa: F401
-    except Exception:
-        return False
-    return jax.default_backend() in ("cpu", "gpu", "cuda", "rocm", "tpu")
-
-
-def configure_compile_cache(cache_dir: Optional[str] = None) -> dict:
-    """Enable jax's persistent compilation cache if opted in.
-
-    ``cache_dir`` overrides the ``REPRO_COMPILE_CACHE_DIR`` env var; with
-    neither set this is a no-op (the default — serving behaviour is
-    unchanged unless a deployment opts in). Safe to call repeatedly
-    (every ``prewarm_compile`` does): reconfiguration only happens when
-    the target directory changes.
-
-    Returns the honesty record::
-
-        {"enabled": bool, "cache_dir": str|None, "backend": str,
-         "supported": bool, "detail": str}
-    """
-    target = cache_dir if cache_dir is not None else os.environ.get(ENV_VAR)
-    if not target:
-        return {
-            "enabled": False, "cache_dir": None,
-            "backend": jax.default_backend(), "supported": cache_supported(),
-            "detail": f"{ENV_VAR} not set — persistent cache off (default)",
-        }
-    target = str(target)
-    if _state["dir"] == target and _state["info"] is not None:
-        return dict(_state["info"])
-
-    supported = cache_supported()
-    info = {
-        "enabled": False, "cache_dir": target,
-        "backend": jax.default_backend(), "supported": supported,
-        "detail": "",
-    }
-    if not supported:
-        info["detail"] = (
-            "backend does not implement the persistent compilation cache; "
-            "compiles stay in-process only"
-        )
-        _state.update(dir=target, info=dict(info))
-        return info
-    try:
-        os.makedirs(target, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", target)
-        for key, val in _CACHE_KEYS:
-            jax.config.update(key, val)
-        # the cache singleton latches on first compile: a process that
-        # already compiled anything ignores a later cache_dir unless the
-        # singleton is reset (observed on the pinned jax 0.4.x)
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception as exc:  # unknown config key on an older jax, ro-fs …
-        info["detail"] = f"configuration failed: {exc!r}"
-        _state.update(dir=target, info=dict(info))
-        return info
-    info["enabled"] = True
-    info["detail"] = "persistent compilation cache active"
-    _state.update(dir=target, info=dict(info))
-    return info
+def configure_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at ``CACHE_DIR`` unless
+    ``JAX_COMPILATION_CACHE_DIR`` already places it. Call before the
+    process's first compile."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))

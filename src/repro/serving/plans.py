@@ -42,8 +42,6 @@ import numpy as np
 from repro.core.belief import empty_log_belief, log_weight
 from repro.core.types import clip_probs
 
-from .compile_cache import configure_compile_cache
-
 
 @dataclasses.dataclass
 class BatchTables:
@@ -457,10 +455,6 @@ class PlanService:
             (post-invalidation warmup; the hot-pair snapshot is taken
             *before* refreshing, so it survives a cost invalidation).
         """
-        # planner cold starts benefit from the same persistent compile
-        # cache as the wave program: the `_sur_greedy_scan` buckets built
-        # here are written to REPRO_COMPILE_CACHE_DIR when opted in
-        configure_compile_cache()
         hot_before = self.hot_pairs(top) if pairs is None and budgets is None else None
         self.refresh()
         if pairs is None:

@@ -54,7 +54,7 @@ from typing import Any, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from repro.core.x64 import x64
 
 from repro.core.belief import tie_break_argmax
 from repro.core.estimation import SuccessProbEstimator
@@ -68,7 +68,6 @@ from repro.distributed.fault import (
 )
 from repro.kernels import ops
 
-from .compile_cache import configure_compile_cache
 from .engine import PoolEngine
 from .plans import GroupPlan, PlanService, stack_plans
 
@@ -211,7 +210,7 @@ def _wave_scan_core(
     each step touches contiguous (B,)/(B, K) slabs. One compile per
     (T, B, K) bucket; the caller pads to buckets.
 
-    Runs in float64 under ``jax.experimental.enable_x64``. Under
+    Runs in float64 under ``repro.core.x64.x64``. Under
     ``use_kernel`` the prefix histories are instead aggregated by a single
     prefix-expanded ``belief_aggregate`` Pallas kernel call, so the stop
     rule sees exactly the float32 beliefs the kernel-backed reference loop
@@ -480,7 +479,7 @@ class PendingRoute:
             if router.device is not None else contextlib.nullcontext()
         )
         scan_fn = _wave_scan if router.donate_buffers else _wave_scan_nodonate
-        with enable_x64(), ctx, _quiet_donation():
+        with x64(), ctx, _quiet_donation():
             self._dev = scan_fn(
                 sched_p, resp_p, w_p, res_p, src_p, valid_p, empty_p,
                 self.stop_margin,
@@ -1011,16 +1010,9 @@ class ThriftRouter:
         as a serving replica taking ragged traffic should. ``max_waves``
         defaults to the pool size (no plan can schedule more arms than
         exist). Returns the number of bucket programs visited; no-op for
-        routers pinned to the reference plane.
-
-        When ``REPRO_COMPILE_CACHE_DIR`` is set (see
-        :func:`repro.serving.compile_cache.configure_compile_cache`) the
-        executables compiled here are written to the persistent cache, so
-        the *next* process's prewarm loads them instead of re-lowering —
-        cold-start latency survives restarts."""
+        routers pinned to the reference plane."""
         if not self.jit_waves:
             return 0
-        configure_compile_cache()    # no-op unless the env var opts in
         if all_batch_buckets:
             b_buckets = sorted({
                 _bucket(b, base=8) for b in range(1, max(1, int(max_batch)) + 1)
@@ -1045,7 +1037,7 @@ class ThriftRouter:
                 scan_fn = (
                     _wave_scan if self.donate_buffers else _wave_scan_nodonate
                 )
-                with enable_x64(), ctx, _quiet_donation():
+                with x64(), ctx, _quiet_donation():
                     scan_fn(
                         np.full((Tp, Bp), -1, np.int32),
                         np.full((Tp, Bp), -1, np.int32),

@@ -56,6 +56,7 @@ _MULTIDEV_SCRIPT = textwrap.dedent(
     import numpy as np
     from repro.configs import get_smoke_config
     from repro.distributed.sharding import AxisRules, batch_specs, param_specs, use_rules
+    from repro.launch.mesh import make_mesh
     from repro.models import LM
     from repro.training import OptimizerConfig, adamw_init, init_train_state, make_train_step
 
@@ -71,7 +72,7 @@ _MULTIDEV_SCRIPT = textwrap.dedent(
     p1, _, m1 = step1(params, opt, batch)
 
     # 2x4 mesh pjit
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = AxisRules(mesh)
     p_sh = param_specs(jax.eval_shape(lambda: params), rules)
     o_sh = param_specs(jax.eval_shape(lambda: opt), rules)

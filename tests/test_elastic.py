@@ -20,6 +20,7 @@ _SCRIPT = textwrap.dedent(
     from repro.configs import get_smoke_config
     from repro.distributed.fault import plan_elastic_remesh, rebatch_for_mesh
     from repro.distributed.sharding import AxisRules, batch_specs, param_specs, use_rules
+    from repro.launch.mesh import make_mesh
     from repro.models import LM
     from repro.training import OptimizerConfig, init_train_state, make_train_step
 
@@ -34,7 +35,7 @@ _SCRIPT = textwrap.dedent(
     losses = []
 
     def run(mesh_shape, global_batch, state, steps):
-        mesh = jax.make_mesh(tuple(mesh_shape.values()), tuple(mesh_shape.keys()))
+        mesh = make_mesh(tuple(mesh_shape.values()), tuple(mesh_shape.keys()))
         rules = AxisRules(mesh)
         p, o = state
         p_sh = param_specs(jax.eval_shape(lambda: p), rules)

@@ -70,7 +70,9 @@ _EP_SCRIPT = textwrap.dedent(
     from repro.distributed.sharding import AxisRules, use_rules
     from repro.models.moe import moe_mlp, moe_mlp_ep
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     T, D, F, E, k = 64, 32, 48, 8, 2
     x = jnp.asarray(rng.normal(0, 1, (T, D)), jnp.float32)

@@ -45,7 +45,9 @@ def test_while_trip_count_scaling():
 
     if len(jax.devices()) < 2:
         pytest.skip("needs >1 host device")
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 2), ("data", "model"))
     xs = NamedSharding(mesh, P(None, "model"))
     ws = NamedSharding(mesh, P("model", None))
     x = jax.ShapeDtypeStruct((16, 64), jnp.float32)

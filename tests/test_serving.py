@@ -91,3 +91,27 @@ def test_straggler_hedge_plan(setup):
     sched = BatchScheduler(router)
     plan = sched.mitigator.hedge_plan([3, 1, 5], slow_arm=1)
     assert plan == [3, 5, 1]
+
+
+@pytest.mark.parametrize("env", ["/srv/jax-cache", None])
+def test_compile_cache_placed_by_env_or_repo(env, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, where set, rules and nothing is changed;
+    otherwise the cache goes to the fixed `.jax_cache` at the repo root."""
+    import pathlib
+
+    import jax
+    from repro.serving import compile_cache
+
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        compile_cache.configure_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo_cache = pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+    assert compile_cache.CACHE_DIR == repo_cache
+    assert got == (before if env else str(repo_cache))
