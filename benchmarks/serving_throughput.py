@@ -277,7 +277,6 @@ def steady_state(router, wl, budget: float, batch: int, n_queries: int,
         "groups": int(sched2.stats["batches"]),
         "spec_jit": int(sched2.stats["spec_jit"]),
         "spec_reference": int(sched2.stats["spec_reference"]),
-        "inflight_peak": int(sched2.stats["inflight_peak"]),
         # and of the saturated-capacity run (coalesced admissions)
         "saturated_flushes": int(sched.stats["flushes"]),
         "saturated_groups": int(sched.stats["batches"]),

@@ -99,6 +99,7 @@ from repro.distributed.sharding import replica_devices
 from .feedback import FeedbackLog, FeedbackReport, FeedbackShard, merge_counts
 from .router import RouteResult, ThriftRouter
 from .scheduler import BatchScheduler, BlockFuture, CostLedger, _Group
+from .telemetry import LatencyHistogram
 
 __all__ = ["ReplicaSet", "ReplicaWorker"]
 
@@ -107,7 +108,7 @@ __all__ = ["ReplicaSet", "ReplicaWorker"]
 #: — plans/ledger — or a per-worker one aggregated separately)
 _CORE_STATS = (
     "batches", "requests", "flushes", "submitted", "completed",
-    "spec_jit", "spec_reference", "inflight_peak",
+    "spec_jit", "spec_reference", "queue_wait_s",
 )
 
 #: non-None sentinel for _RouteView.rng: the retire path steps a
@@ -295,9 +296,6 @@ class _WorkerScheduler(BatchScheduler):
             view, g.arrival, g.part_sinks, g.part_id, g.part_pos,
             ids=g.ids, tenants=g.tenants, reserved=g.reserved,
         ))
-        self._stats["inflight_peak"] = max(
-            self._stats["inflight_peak"], len(self._inflight)
-        )
 
 
 class ReplicaWorker:
@@ -645,9 +643,6 @@ class ReplicaSet:
                     g.part_id, g.part_pos, g.ids, g.tenants, g.reserved,
                     g.mode,
                 )
-                w.sched._stats["inflight_peak"] = max(
-                    w.sched._stats["inflight_peak"], len(w.sched._inflight)
-                )
             else:
                 self._launch_fused(entries)
 
@@ -816,24 +811,11 @@ class ReplicaSet:
         return out
 
     def latency_stats(self) -> Dict[str, float]:
-        """Completion-latency summary pooled across every replica."""
-        arrs = []
-        count = 0
-        for w in self.workers:
-            count += int(w.sched._stats["completed"])
-            if w.sched._latencies:
-                w.sched._trim_latencies()
-                arrs.append(w.sched._latencies[0])
-        if not arrs:
-            return {"count": 0}
-        lat = np.concatenate(arrs)
-        return {
-            "count": count,
-            "p50_s": float(np.percentile(lat, 50)),
-            "p99_s": float(np.percentile(lat, 99)),
-            "mean_s": float(lat.mean()),
-            "max_s": float(lat.max()),
-        }
+        """Completion-latency summary pooled across every replica: the
+        workers' histogram counts added bucket by bucket."""
+        return LatencyHistogram.pooled(
+            w.sched._latency for w in self.workers
+        ).summary()
 
     def stragglers(self) -> List[int]:
         """Arms any replica's mitigator currently flags."""

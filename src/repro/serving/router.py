@@ -70,6 +70,7 @@ from repro.kernels import ops
 
 from .engine import PoolEngine
 from .plans import GroupPlan, PlanService, stack_plans
+from .telemetry import span
 
 # retained name for PR 1 call sites / pickles
 _GroupPlan = GroupPlan
@@ -389,6 +390,12 @@ class PendingRoute:
     # jit kind: speculative gather + async device dispatch
     # ------------------------------------------------------------------
     def _dispatch_jit(self):
+        with span("thrift.route.gather"):
+            self._gather_jit()
+        with span("thrift.route.launch"):
+            self._launch_jit()
+
+    def _gather_jit(self):
         router, T, B = self.router, self.T, self.B
         sched_T, payloads = self.sched_T, self.payloads
         engine = router.engine
@@ -443,6 +450,10 @@ class PendingRoute:
             self._rank = self._navail = None
         self._src, self._valid = src, valid
 
+    def _launch_jit(self):
+        router, T, B = self.router, self.T, self.B
+        sched_T, resp_T = self.sched_T, self.resp_T
+        src, valid = self._src, self._valid
         # Pad to compile buckets so serving traffic with drifting batch
         # sizes / plan depths reuses a handful of compiled programs; the
         # whole pipeline is wave-major, so padding never transposes.
@@ -512,6 +523,14 @@ class PendingRoute:
         )
 
     def _finalize_jit(self) -> RouteResult:
+        with span("thrift.finalize.wait"):
+            # the outputs of one execution finish together; waiting on one
+            # is cheaper than jax.block_until_ready on all three
+            self._dev[0].block_until_ready()
+        with span("thrift.finalize"):
+            return self._finalize_jit_host()
+
+    def _finalize_jit_host(self) -> RouteResult:
         s_d, pred_d, beliefs_d = self._dev
         B, T, L = self.B, self.T, self.L
         stop_wave = np.asarray(s_d)[:B]          # waves invoked per query
@@ -650,9 +669,13 @@ class PendingRoute:
         rng stream. After exhaustion returns empty rows.
         """
         assert self.kind == "reference", "step() is for reference routes"
-        K = self.router.num_classes
         if self._exhausted:
             return np.zeros(0, np.int64), np.zeros(0, np.int64)
+        with span("thrift.route.step"):
+            return self._step()
+
+    def _step(self):
+        K = self.router.num_classes
         cur, t = self.cur, self._t
         bel = self._beliefs_rows(cur)
         if t >= self.T:
@@ -705,6 +728,10 @@ class PendingRoute:
     def _finalize_reference(self) -> RouteResult:
         while not self._exhausted:
             self.step()
+        with span("thrift.finalize"):
+            return self._finalize_reference_host()
+
+    def _finalize_reference_host(self) -> RouteResult:
         responses = np.ascontiguousarray(self.resp_T.T)
         if self.router.use_kernel:
             beliefs = self.router._kernel_beliefs(
@@ -962,32 +989,31 @@ class ThriftRouter:
         budgets = np.broadcast_to(np.asarray(budget, np.float64), (B,))
         if B == 0:
             return PendingRoute(self, "empty", result=self._empty_result(budgets))
-        self.plans.refresh()
-        cluster_ids, sched_T, w_T, res_T, wc_T, empty, planned = self._plan_batch(
-            embeddings, budgets
-        )
-        spec_cost = self.speculation_cost(sched_T, wc_T)
-        if mode == "auto":
-            # a router pinned to the reference plane (jit_waves=False — the
-            # pre-metered-flag way to forbid speculation) keeps it under
-            # auto, regardless of per-arm flags
-            if not self.jit_waves or spec_cost > speculation_threshold:
-                kind = "reference"
-            else:
-                kind = "jit"
-        elif mode in ("jit", "reference"):
-            kind = mode
-        else:
+        if mode not in ("auto", "jit", "reference"):
             raise ValueError(f"unknown route mode {mode!r}")
-        pending = PendingRoute(
-            self, kind,
-            budgets=budgets, cluster_ids=cluster_ids, sched_T=sched_T,
-            w_T=w_T, res_T=res_T, wc_T=wc_T, empty=empty, planned=planned,
-            payloads=self.engine.prepare_payloads(queries),
-            stop_margin=float(stop_margin), rng=rng, spec_cost=spec_cost,
-            plan_version=getattr(self.estimator, "plan_version", 0),
-            fault_row_offset=fault_row_offset,
-        )
+        with span("thrift.route.plan"):
+            self.plans.refresh()
+            (cluster_ids, sched_T, w_T, res_T, wc_T, empty,
+             planned) = self._plan_batch(embeddings, budgets)
+            spec_cost = self.speculation_cost(sched_T, wc_T)
+            kind = mode
+            if mode == "auto":
+                # a router pinned to the reference plane (jit_waves=False —
+                # the pre-metered-flag way to forbid speculation) keeps it
+                # under auto, regardless of per-arm flags
+                if not self.jit_waves or spec_cost > speculation_threshold:
+                    kind = "reference"
+                else:
+                    kind = "jit"
+            pending = PendingRoute(
+                self, kind,
+                budgets=budgets, cluster_ids=cluster_ids, sched_T=sched_T,
+                w_T=w_T, res_T=res_T, wc_T=wc_T, empty=empty, planned=planned,
+                payloads=self.engine.prepare_payloads(queries),
+                stop_margin=float(stop_margin), rng=rng, spec_cost=spec_cost,
+                plan_version=getattr(self.estimator, "plan_version", 0),
+                fault_row_offset=fault_row_offset,
+            )
         if kind == "jit":
             pending._dispatch_jit()
         return pending

@@ -75,6 +75,7 @@ from repro.distributed.fault import (
 )
 
 from .feedback import DegradationTracker, FeedbackLog, FeedbackReport
+from .telemetry import LatencyHistogram, span
 
 
 @dataclasses.dataclass
@@ -715,9 +716,7 @@ class BatchScheduler:
         self._queue_version = 0
         self._prefetched_version = -1
         self._inflight: collections.deque = collections.deque()  # of _Group
-        self._latencies: List[np.ndarray] = []
-        self._lat_window = 1 << 17        # newest samples kept for percentiles
-        self._lat_buffered = 0
+        self._latency = LatencyHistogram()
         self.mitigator = StragglerMitigator(num_workers=len(router.engine.arms))
         self.arm_query_totals = np.zeros(len(router.engine.arms), np.int64)
         self._stats: Dict[str, float] = {
@@ -728,7 +727,7 @@ class BatchScheduler:
             "completed": 0,
             "spec_jit": 0,       # groups served by the speculative jit plane
             "spec_reference": 0, # groups served by the compacting plane
-            "inflight_peak": 0,
+            "queue_wait_s": 0.0, # admission - arrival, summed over rows
         }
         self._sync_plan_stats()
 
@@ -829,10 +828,10 @@ class BatchScheduler:
         plans = getattr(self.router, "plans", None)
         if plans is None:
             return
-        emb = np.concatenate([s.emb for s in self._queue])
-        budgets = np.concatenate([s.budgets for s in self._queue])
-        plans.prefetch_for(emb, budgets)
-        self._sync_plan_stats()
+        with span("thrift.prefetch"):
+            emb = np.concatenate([s.emb for s in self._queue])
+            budgets = np.concatenate([s.budgets for s in self._queue])
+            plans.prefetch_for(emb, budgets)
 
     # ------------------------------------------------------------------
     # Admission
@@ -883,21 +882,24 @@ class BatchScheduler:
         n = emb.shape[0]
         if n == 0:
             return BlockFuture(self, 0)   # already done; never enqueued
-        budgets = np.broadcast_to(np.asarray(budgets, np.float64), (n,)).copy()
-        if arrival_s is None:
-            arrival = np.full(n, time.monotonic())
-        else:
-            arrival = np.broadcast_to(
-                np.asarray(arrival_s, np.float64), (n,)
+        with span("thrift.submit"):
+            budgets = np.broadcast_to(
+                np.asarray(budgets, np.float64), (n,)
             ).copy()
-        slo = np.full(n, np.nan if slo_s is None else float(slo_s))
-        ids = self._alloc_ids(n)
-        blk = BlockFuture(self, n, request_ids=ids)
-        tenants = np.broadcast_to(np.asarray(tenant, object), (n,)).copy()
-        self.submit_block(
-            payloads, emb, budgets, arrival, slo, blk, np.arange(n), ids,
-            tenants,
-        )
+            if arrival_s is None:
+                arrival = np.full(n, time.monotonic())
+            else:
+                arrival = np.broadcast_to(
+                    np.asarray(arrival_s, np.float64), (n,)
+                ).copy()
+            slo = np.full(n, np.nan if slo_s is None else float(slo_s))
+            ids = self._alloc_ids(n)
+            blk = BlockFuture(self, n, request_ids=ids)
+            tenants = np.broadcast_to(np.asarray(tenant, object), (n,)).copy()
+            self._enqueue(
+                payloads, emb, budgets, arrival, slo, blk, np.arange(n), ids,
+                tenants,
+            )
         return blk
 
     def submit_block(self, payloads, emb, budgets, arrival, slo, sink, pos,
@@ -907,6 +909,12 @@ class BatchScheduler:
         :class:`~repro.serving.replica.ReplicaSet`) uses to scatter one
         caller-visible :class:`BlockFuture` across several schedulers.
         ``submit_many`` is this plus the array building."""
+        with span("thrift.submit"):
+            self._enqueue(payloads, emb, budgets, arrival, slo, sink, pos,
+                          ids, tenants)
+
+    def _enqueue(self, payloads, emb, budgets, arrival, slo, sink, pos, ids,
+                 tenants) -> None:
         n = budgets.shape[0]
         self._queue.append(_Segment(
             payloads, emb, budgets, arrival, slo, sink, pos, ids,
@@ -1086,39 +1094,43 @@ class BatchScheduler:
         consistent estimator version and a fold can never land mid-wave.
         With a cost ledger bound, this is also where tenant limits are
         enforced (reserve / downgrade / reject)."""
-        self.apply_feedback()
-        take = self._take_batch()
-        if not take:
-            return
-        (payloads, emb, budgets, arrival, part_sinks, part_id, part_pos,
-         ids, tenants) = self._stack_segments(take)
-        self._stats["flushes"] += 1
-        reserved = None
-        if self.ledger is not None:
-            admitted, budgets, reserved = self._admit_ledger(
-                budgets, tenants, arrival, part_sinks, part_id, part_pos,
-                ids=ids,
+        with span("thrift.admit"):
+            self.apply_feedback()
+            take = self._take_batch()
+            if not take:
+                return
+            (payloads, emb, budgets, arrival, part_sinks, part_id, part_pos,
+             ids, tenants) = self._stack_segments(take)
+            self._stats["flushes"] += 1
+            reserved = None
+            if self.ledger is not None:
+                admitted, budgets, reserved = self._admit_ledger(
+                    budgets, tenants, arrival, part_sinks, part_id, part_pos,
+                    ids=ids,
+                )
+                if admitted.size < budgets.shape[0]:
+                    if admitted.size == 0:
+                        return
+                    payloads = self._index_payloads(payloads, admitted)
+                    emb, budgets = emb[admitted], budgets[admitted]
+                    arrival, part_pos = arrival[admitted], part_pos[admitted]
+                    ids, tenants = ids[admitted], tenants[admitted]
+                    reserved = reserved[admitted]
+                    if part_id is not None:
+                        part_id = part_id[admitted]
+            self._stats["requests"] += budgets.shape[0]
+            self._stats["queue_wait_s"] += float(
+                (time.monotonic() - arrival).sum()
             )
-            if admitted.size < budgets.shape[0]:
-                if admitted.size == 0:
-                    return
-                payloads = self._index_payloads(payloads, admitted)
-                emb, budgets = emb[admitted], budgets[admitted]
-                arrival, part_pos = arrival[admitted], part_pos[admitted]
-                ids, tenants = ids[admitted], tenants[admitted]
-                reserved = reserved[admitted]
-                if part_id is not None:
-                    part_id = part_id[admitted]
-        self._stats["requests"] += budgets.shape[0]
-        mode = self._route_mode()
-        if (budgets == budgets[0]).all():
-            group_rows = [None]                    # whole batch, no split
-        else:
-            # one group per budget, first-occurrence order, FIFO inside
-            _, first = np.unique(budgets, return_index=True)
-            group_rows = [
-                np.flatnonzero(budgets == budgets[i]) for i in np.sort(first)
-            ]
+            mode = self._route_mode()
+            if (budgets == budgets[0]).all():
+                group_rows = [None]                    # whole batch, no split
+            else:
+                # one group per budget, first-occurrence order, FIFO inside
+                _, first = np.unique(budgets, return_index=True)
+                group_rows = [
+                    np.flatnonzero(budgets == budgets[i]) for i in np.sort(first)
+                ]
         for rows in group_rows:
             if rows is None:
                 g_payloads, g_emb, g_budgets = payloads, emb, budgets
@@ -1136,9 +1148,6 @@ class BatchScheduler:
                 g_payloads, g_emb, g_budgets, g_arrival, part_sinks, g_id,
                 g_pos, g_ids, g_tenants, g_reserved, mode,
             )
-        self._stats["inflight_peak"] = max(
-            self._stats["inflight_peak"], len(self._inflight)
-        )
 
     def _launch(self, payloads, emb, budgets, arrival, part_sinks, part_id,
                 part_pos, ids, tenants, reserved, mode):
@@ -1162,10 +1171,7 @@ class BatchScheduler:
                       now: float):
         """Columnar completion: fill each contributing future's slice."""
         latencies = now - group.arrival[rows]
-        self._latencies.append(latencies)
-        self._lat_buffered += latencies.shape[0]
-        if self._lat_buffered > 2 * self._lat_window:
-            self._trim_latencies()
+        self._latency.add(latencies)
         self._stats["completed"] += rows.shape[0]
         if group.part_id is None:
             group.part_sinks[0]._fill(
@@ -1194,31 +1200,36 @@ class BatchScheduler:
                 wave = pending._t
                 rows, preds = pending.step()
                 if rows.size:
+                    with span("thrift.retire"):
+                        self._resolve_rows(
+                            group, rows, preds, pending.costs[rows],
+                            pending.planned[rows], pending.cluster_ids[rows],
+                            pending.budgets[rows],
+                            np.full(rows.shape[0], min(wave, pending.T),
+                                    np.int64),
+                            "reference", time.monotonic(),
+                        )
+                        resolved[rows] = True
+            res = pending.result()
+            with span("thrift.retire"):
+                left = all_rows[~resolved]
+                if left.size:   # defensive: every row should resolve via steps
                     self._resolve_rows(
-                        group, rows, preds, pending.costs[rows],
-                        pending.planned[rows], pending.cluster_ids[rows],
-                        pending.budgets[rows],
-                        np.full(rows.shape[0], min(wave, pending.T), np.int64),
+                        group, left, res.predictions[left], res.costs[left],
+                        res.planned_costs[left], res.clusters[left],
+                        res.budgets[left], res.stop_waves[left],
                         "reference", time.monotonic(),
                     )
-                    resolved[rows] = True
-            res = pending.result()
-            left = all_rows[~resolved]
-            if left.size:   # defensive: every row should resolve via steps
-                self._resolve_rows(
-                    group, left, res.predictions[left], res.costs[left],
-                    res.planned_costs[left], res.clusters[left],
-                    res.budgets[left], res.stop_waves[left],
-                    "reference", time.monotonic(),
-                )
+                self._account(res, group)
         else:
             res = pending.result()
-            self._resolve_rows(
-                group, np.arange(group.n), res.predictions, res.costs,
-                res.planned_costs, res.clusters, res.budgets,
-                res.stop_waves, pending.kind, time.monotonic(),
-            )
-        self._account(res, group)
+            with span("thrift.retire"):
+                self._resolve_rows(
+                    group, np.arange(group.n), res.predictions, res.costs,
+                    res.planned_costs, res.clusters, res.budgets,
+                    res.stop_waves, pending.kind, time.monotonic(),
+                )
+                self._account(res, group)
         return group.n
 
     def _account(self, res, group: Optional[_Group] = None):
@@ -1279,7 +1290,6 @@ class BatchScheduler:
         if (self.ledger is not None and group is not None
                 and group.tenants is not None):
             self._settle(res, group)
-        self._sync_plan_stats()
 
     def _settle(self, res, group: _Group):
         """Retire-time ledger settlement: release each tenant's admission
@@ -1369,29 +1379,12 @@ class BatchScheduler:
     # ------------------------------------------------------------------
     # Latency accounting
     # ------------------------------------------------------------------
-    def _trim_latencies(self):
-        """Keep only the newest ``_lat_window`` samples, so a long-running
-        server's latency history stays bounded (the percentile summary is a
-        sliding window, like the StragglerMitigator's)."""
-        lat = np.concatenate(self._latencies)[-self._lat_window:]
-        self._latencies = [lat]
-        self._lat_buffered = lat.shape[0]
-
     def latency_stats(self) -> Dict[str, float]:
-        """Completion-latency summary: ``count`` covers everything ever
-        completed; the percentiles cover the newest ``_lat_window``
-        (default 128k) samples."""
-        if not self._latencies:
-            return {"count": 0}
-        self._trim_latencies()
-        lat = self._latencies[0]
-        return {
-            "count": int(self._stats["completed"]),
-            "p50_s": float(np.percentile(lat, 50)),
-            "p99_s": float(np.percentile(lat, 99)),
-            "mean_s": float(lat.mean()),
-            "max_s": float(lat.max()),
-        }
+        """Completion-latency summary of every request completed since
+        start: ``count``, ``mean_s`` and ``max_s`` exact, ``p50_s`` and
+        ``p99_s`` read from :class:`~repro.serving.telemetry.LatencyHistogram`
+        bucket edges (at most 5 % high)."""
+        return self._latency.summary()
 
     # ------------------------------------------------------------------
     # PR 2 one-shot API (kept for batch callers and the equivalence tests)
@@ -1428,6 +1421,9 @@ class BatchScheduler:
                 reserved = reserved[admitted]
                 if part_id is not None:
                     part_id = part_id[admitted]
+        self._stats["queue_wait_s"] += float(
+            (time.monotonic() - arrival).sum()
+        )
         pending = self.router.begin_route(
             payloads, emb, budgets, mode=self._route_mode(),
             speculation_threshold=self.speculation_threshold,
