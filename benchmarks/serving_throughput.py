@@ -493,16 +493,17 @@ def cross_device(router, wl, budget: float, per_batch: int, make_router,
         ).copy()
         valid = np.ones((Tp, rows), bool)
         empty = np.zeros(rows, np.float64)
-        return (sched, resp, w, res, src, valid, empty)
+        return router_mod._pack_wave(
+            sched, resp, w, res, src, valid, empty, router_mod.STOP_MARGIN
+        )
 
-    def run_wave(args_list):
+    def run_wave(packed_list):
         with router_mod._quiet_donation():
             outs = [
                 router_mod._wave_scan(
-                    *a, router_mod.STOP_MARGIN,
-                    num_classes=K, use_kernel=router.use_kernel,
+                    packed, num_classes=K, use_kernel=router.use_kernel,
                 )
-                for a in args_list
+                for packed in packed_list
             ]
         for o in outs:
             jax.block_until_ready(o)

@@ -781,6 +781,8 @@ class ReplicaSet:
             for k in _CORE_STATS:
                 out[k] += w.sched._stats[k]
         out.update(self.plans.stats())
+        for k in ("wave_uploads", "wave_upload_bytes"):
+            out[k] = sum(w.router.upload_stats()[k] for w in self.workers)
         if self.feedback is not None:
             fb: Dict[str, float] = {}
             for log in [self.feedback] + [w.sched.feedback for w in self.workers]:

@@ -303,7 +303,7 @@ def _wave_scan_core(
 def _quiet_donation():
     """Donation is declarative — XLA aliases the donated inputs it can use
     and warns once at compile time about the rest; the caller-side contract
-    ("the staged tables are dead after dispatch") is what the wrappers and
+    ("the staged buffers are dead after dispatch") is what the wrappers and
     the `donation-contract` lint rule enforce, so the partial-use warning
     is expected noise at the dispatch seams."""
     with warnings.catch_warnings():
@@ -312,21 +312,84 @@ def _quiet_donation():
         )
         yield
 
-# The serving default donates the staged response/weight/residual wave
-# tables: `_dispatch_jit` builds them as throwaway locals (host numpy —
-# the jit transfers a fresh device copy per call and donates that copy),
-# re-reads nothing after the call, and `prewarm_compile` passes dummies.
-# `_wave_scan_nodonate` is the bit-identical twin for callers that keep
-# the staged device buffers alive (`ThriftRouter(donate_buffers=False)`).
-# Each wrapper owns one compile per (T, B, K) bucket.
+
+#: Planes of the packed wave layout, in row order, each (Tp, Bp): schedule,
+#: responses, weights, residual, failover source, valid. Row ``6 * Tp``
+#: holds ``empty`` and row ``6 * Tp + 1`` the stop margin.
+_WAVE_PLANES = 6
+
+
+@functools.lru_cache(maxsize=None)
+def _wave_pad(Tp: int, Bp: int) -> np.ndarray:
+    """The packed buffer of a (Tp, Bp) bucket with every cell at its
+    plane's pad value: schedule and responses -1, weight 0, residual -inf,
+    source wave the identity, valid 0, empty 0. Read-only; copied per
+    group."""
+    buf = np.zeros((_WAVE_PLANES * Tp + 2, Bp), np.float64)
+    buf[:2 * Tp] = -1.0
+    buf[3 * Tp:4 * Tp] = -np.inf
+    buf[4 * Tp:5 * Tp] = np.arange(Tp)[:, None]
+    buf.setflags(write=False)
+    return buf
+
+
+def _pack_wave(sched, resp, weights, residual, src, valid, empty,
+               stop_margin) -> np.ndarray:
+    """One group's wave inputs as the one host buffer the wave program
+    takes: float64, ``(6 * Tp + 2, Bp)`` for the compile bucket (Tp, Bp)
+    of the (T, B) tables, pads as :func:`_wave_pad` (buckets, so traffic
+    with drifting batch sizes and plan depths reuses a handful of compiled
+    programs). The integer and boolean planes are small integers, which
+    float64 holds exactly; the program casts them back. One buffer is one
+    host-to-device placement per call; every further array argument would
+    be one more."""
+    T, B = sched.shape
+    Tp, Bp = _bucket(T, base=4), _bucket(B, base=8)
+    buf = _wave_pad(Tp, Bp).copy()
+    for i, plane in enumerate((sched, resp, weights, residual, src, valid)):
+        buf[i * Tp:i * Tp + T, :B] = plane
+    buf[_WAVE_PLANES * Tp, :B] = empty
+    buf[_WAVE_PLANES * Tp + 1] = stop_margin
+    return buf
+
+
+def _wave_scan_packed(packed, *, num_classes: int, use_kernel: bool):
+    """:func:`_wave_scan_core` over a :func:`_pack_wave` buffer: static
+    slices at the bucket's row offsets, the integer planes cast back
+    exactly, the loop itself unchanged."""
+    Tp = (packed.shape[0] - 2) // _WAVE_PLANES
+
+    def plane(i):
+        return packed[i * Tp:(i + 1) * Tp]
+
+    return _wave_scan_core(
+        plane(0).astype(jnp.int32),
+        plane(1).astype(jnp.int32),
+        plane(2),
+        plane(3),
+        plane(4).astype(jnp.int32),
+        plane(5) != 0,
+        packed[_WAVE_PLANES * Tp],
+        packed[_WAVE_PLANES * Tp + 1, 0],
+        num_classes=num_classes,
+        use_kernel=use_kernel,
+    )
+
+
+# The serving default donates the packed buffer: `_pack_wave` builds it as
+# a throwaway local (host numpy — the jit transfers a fresh device copy per
+# call and donates that copy) and nothing re-reads it after the call.
+# `_wave_scan_nodonate` is the bit-identical twin for callers that keep the
+# staged device buffer alive (`ThriftRouter(donate_buffers=False)`). Each
+# wrapper owns one compile per (T, B, K) bucket.
 _wave_scan = functools.partial(
     jax.jit, static_argnames=("num_classes", "use_kernel"),
-    donate_argnums=(1, 2, 3),
-)(_wave_scan_core)
+    donate_argnums=(0,),
+)(_wave_scan_packed)
 
 _wave_scan_nodonate = functools.partial(
     jax.jit, static_argnames=("num_classes", "use_kernel"),
-)(_wave_scan_core)
+)(_wave_scan_packed)
 
 
 class PendingRoute:
@@ -451,51 +514,10 @@ class PendingRoute:
         self._src, self._valid = src, valid
 
     def _launch_jit(self):
-        router, T, B = self.router, self.T, self.B
-        sched_T, resp_T = self.sched_T, self.resp_T
-        src, valid = self._src, self._valid
-        # Pad to compile buckets so serving traffic with drifting batch
-        # sizes / plan depths reuses a handful of compiled programs; the
-        # whole pipeline is wave-major, so padding never transposes.
-        Bp, Tp = _bucket(B, base=8), _bucket(T, base=4)
-        sched_p = np.full((Tp, Bp), -1, np.int32)
-        sched_p[:T, :B] = sched_T
-        resp_p = np.full((Tp, Bp), -1, np.int32)
-        resp_p[:T, :B] = resp_T
-        w_p = np.zeros((Tp, Bp), np.float64)
-        w_p[:T, :B] = self.w_T
-        res_p = np.full((Tp, Bp), -np.inf, np.float64)
-        res_p[:T, :B] = self.res_T
-        src_p = np.broadcast_to(
-            np.arange(Tp, dtype=np.int32)[:, None], (Tp, Bp)
-        ).copy()
-        src_p[:T, :B] = src
-        valid_p = np.zeros((Tp, Bp), bool)
-        valid_p[:T, :B] = valid
-        empty_p = np.zeros(Bp, np.float64)
-        empty_p[:B] = self.empty
-
-        # Device pinning rides jax.default_device, not an explicit
-        # jax.device_put: committing the seven padded tables per dispatch
-        # measures ~5x the whole dispatch cost on the CPU backend, while
-        # the context manager just steers where jit places the uncommitted
-        # numpy args (~free) and still caches one executable per (bucket,
-        # device). Placement stays inside the x64 context — materializing
-        # f64 arrays outside it would silently downcast to f32 and change
-        # the wave program's numerics. No host references to the staged
-        # buffers are retained (args are locals), so the carry is
-        # donation-safe — XLA may alias the input buffers freely.
-        ctx = (
-            jax.default_device(router.device)
-            if router.device is not None else contextlib.nullcontext()
-        )
-        scan_fn = _wave_scan if router.donate_buffers else _wave_scan_nodonate
-        with x64(), ctx, _quiet_donation():
-            self._dev = scan_fn(
-                sched_p, resp_p, w_p, res_p, src_p, valid_p, empty_p,
-                self.stop_margin,
-                num_classes=router.num_classes, use_kernel=router.use_kernel,
-            )
+        self._dev = self.router._run_wave(_pack_wave(
+            self.sched_T, self.resp_T, self.w_T, self.res_T,
+            self._src, self._valid, self.empty, self.stop_margin,
+        ))
 
     def ready(self) -> bool:
         """Non-blocking: has the dispatched device program finished? Host-
@@ -811,7 +833,7 @@ class ThriftRouter:
         self.use_kernel = bool(use_kernel)
         self.jit_waves = bool(jit_waves)
         self.failover = bool(failover)
-        # Donate the staged wave tables to XLA (`_wave_scan` vs its
+        # Donate the packed wave buffer to XLA (`_wave_scan` vs its
         # `_nodonate` twin): bit-identical either way; off keeps the
         # transferred device buffers readable after dispatch (debugging).
         self.donate_buffers = bool(donate_buffers)
@@ -822,6 +844,10 @@ class ThriftRouter:
         # holds one executable per (bucket, device) pair, so prewarming
         # happens per pinned device (see ReplicaSet.prewarm_compile).
         self.device = None
+        # host buffers placed for wave programs, and their bytes (padding
+        # included): one per jit-plane group, plus prewarm's
+        self.wave_uploads = 0
+        self.wave_upload_bytes = 0
         self.selector = ThriftLLM(
             engine.costs, eps=eps, delta=delta, seed=seed, use_kernel=use_kernel
         )
@@ -1047,38 +1073,49 @@ class ThriftRouter:
             b_buckets = [_bucket(int(max_batch), base=8)]
         waves = int(max_waves) if max_waves is not None else len(self.engine.arms)
         t_buckets = sorted({_bucket(t, base=4) for t in range(1, max(1, waves) + 1)})
-        # jit caches one executable per (bucket, device): a router pinned
-        # to a device must warm that device's cache entries, not the
-        # default device's — same jax.default_device placement as the
-        # dispatch seam (_dispatch_jit), so the warmed entry is exactly
-        # the one traffic hits (the context is single-use: built per
-        # bucket pair)
+        # an all-unscheduled group of each bucket, through the same pack
+        # and placement as traffic, so the warmed executable (per bucket
+        # and pinned device) is exactly the one traffic hits
         for Bp in b_buckets:
             for Tp in t_buckets:
-                ctx = (
-                    jax.default_device(self.device)
-                    if self.device is not None
-                    else contextlib.nullcontext()
-                )
-                scan_fn = (
-                    _wave_scan if self.donate_buffers else _wave_scan_nodonate
-                )
-                with x64(), ctx, _quiet_donation():
-                    scan_fn(
-                        np.full((Tp, Bp), -1, np.int32),
-                        np.full((Tp, Bp), -1, np.int32),
-                        np.zeros((Tp, Bp), np.float64),
-                        np.full((Tp, Bp), -np.inf, np.float64),
-                        np.broadcast_to(
-                            np.arange(Tp, dtype=np.int32)[:, None], (Tp, Bp)
-                        ).copy(),
-                        np.zeros((Tp, Bp), bool),
-                        np.zeros(Bp, np.float64),
-                        STOP_MARGIN,
-                        num_classes=self.num_classes,
-                        use_kernel=self.use_kernel,
-                    )
+                none = np.full((Tp, Bp), -1, np.int32)
+                self._run_wave(_pack_wave(
+                    none, none, np.zeros((Tp, Bp)), np.full((Tp, Bp), -np.inf),
+                    np.broadcast_to(np.arange(Tp)[:, None], (Tp, Bp)),
+                    none >= 0, np.zeros(Bp), STOP_MARGIN,
+                ))
         return len(b_buckets) * len(t_buckets)
+
+    def _run_wave(self, packed: np.ndarray):
+        """Place one :func:`_pack_wave` buffer and start its wave program;
+        returns the program's three outputs, still on the device.
+
+        Device pinning rides jax.default_device, not an explicit
+        jax.device_put: committing the buffer per dispatch measured ~5x
+        the whole dispatch cost on the CPU backend, while the context
+        manager just steers where jit places the uncommitted numpy buffer
+        and still caches one executable per (bucket, device). Placement
+        stays inside the x64 context — a float64 buffer materialized
+        outside it would silently downcast to float32."""
+        self.wave_uploads += 1
+        self.wave_upload_bytes += packed.nbytes
+        ctx = (
+            jax.default_device(self.device)
+            if self.device is not None else contextlib.nullcontext()
+        )
+        scan_fn = _wave_scan if self.donate_buffers else _wave_scan_nodonate
+        with x64(), ctx, _quiet_donation():
+            return scan_fn(
+                packed, num_classes=self.num_classes, use_kernel=self.use_kernel
+            )
+
+    def upload_stats(self) -> dict:
+        """Wave-program upload counters (mirrored into
+        ``BatchScheduler.stats``): host buffers placed and their bytes."""
+        return {
+            "wave_uploads": self.wave_uploads,
+            "wave_upload_bytes": self.wave_upload_bytes,
+        }
 
     # ------------------------------------------------------------------
     def route_batch(

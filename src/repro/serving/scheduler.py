@@ -745,12 +745,17 @@ class BatchScheduler:
     def _sync_plan_stats(self):
         """Mirror the router's PlanService counters into ``stats`` so the
         serving control plane sees plan-cache hit/miss/invalidation rates
-        without reaching into router internals. With feedback enabled, the
+        without reaching into router internals, and the router's wave
+        upload counters (``wave_uploads``, ``wave_upload_bytes``) beside
+        them. With feedback enabled, the
         FeedbackLog's label/drift counters are mirrored too — together they
         are the hit/miss/replan/drift dashboard of the online loop."""
         plans = getattr(self.router, "plans", None)
         if plans is not None:
             self._stats.update(plans.stats())
+        uploads = getattr(self.router, "upload_stats", None)
+        if uploads is not None:
+            self._stats.update(uploads())
         if self.feedback is not None:
             self._stats.update(self.feedback.stats())
         if self.degradation is not None:

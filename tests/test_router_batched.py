@@ -341,3 +341,139 @@ def test_scheduler_group_accounting_and_used_arm_latency():
     # per-query budgets enforced per group
     budgets = np.asarray([r.budget for r in batch])
     assert (res.costs <= budgets + 1e-12).all()
+
+
+# ---------------------------------------------------------------------------
+# The packed wave upload: one host buffer per routed group
+# ---------------------------------------------------------------------------
+
+
+def _random_wave_group(rng, T, B, K, L, faults):
+    """One group's unpadded wave tables as `PendingRoute` stages them:
+    plans of ragged depth (unscheduled cells, -inf residuals past each
+    plan's end), responses drawn even on unscheduled cells as the pooled
+    gather does, and under ``faults`` a non-identity failover gather."""
+    from repro.distributed.fault import failover_gather
+
+    depth = rng.integers(0, T + 1, B)
+    live = np.arange(T)[:, None] < depth[None, :]
+    sched = np.where(live, rng.integers(0, L, (T, B)), -1)
+    resp = rng.integers(-1, K, (T, B))
+    w = np.where(live, rng.random((T, B)) * 3.0, 0.0)
+    tail = np.cumsum(w[::-1], axis=0)[::-1]
+    res = np.where(live, np.log(tail + 1e-3) - 1.0, -np.inf)
+    if faults:
+        src, valid, _, _ = failover_gather(sched, rng.random((T, B)) < 0.3)
+        assert (src != np.arange(T)[:, None])[valid].any()
+    else:
+        src = np.broadcast_to(np.arange(T, dtype=np.int32)[:, None], (T, B))
+        valid = sched >= 0
+    empty = -rng.random(B)
+    return sched, resp, w, res, src, valid, empty
+
+
+def _padded_tables(sched, resp, w, res, src, valid, empty):
+    """The seven separate padded tables of the unpacked layout."""
+    from repro.serving.router import _bucket
+
+    T, B = sched.shape
+    Tp, Bp = _bucket(T, base=4), _bucket(B, base=8)
+
+    def pad(x, fill, dtype):
+        out = np.full((Tp, Bp), fill, dtype)
+        out[:T, :B] = x
+        return out
+
+    src_p = np.broadcast_to(np.arange(Tp, dtype=np.int32)[:, None], (Tp, Bp)).copy()
+    src_p[:T, :B] = src
+    empty_p = np.zeros(Bp)
+    empty_p[:B] = empty
+    return (pad(sched, -1, np.int32), pad(resp, -1, np.int32),
+            pad(w, 0.0, np.float64), pad(res, -np.inf, np.float64), src_p,
+            pad(valid, False, bool), empty_p)
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize("faults", [False, True], ids=["identity", "failover"])
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["xla", "kernel"])
+def test_packed_wave_program_is_bit_identical(use_kernel, faults):
+    """The packed program (`_wave_scan` over `_pack_wave`) returns exactly
+    what `_wave_scan_core` returns on the seven unpacked tables, on groups
+    whose B and T are not bucket multiples."""
+    import functools
+
+    import jax
+
+    from repro.core.selection import STOP_MARGIN
+    from repro.core.x64 import x64
+    from repro.serving import router as router_mod
+
+    unpacked = functools.partial(
+        jax.jit, static_argnames=("num_classes", "use_kernel")
+    )(router_mod._wave_scan_core)
+    rng = np.random.default_rng(15 + 2 * use_kernel + faults)
+    for T, B, K, margin in ((3, 13, 4, STOP_MARGIN), (9, 51, 4, STOP_MARGIN),
+                            (6, 37, 5, 0.3)):
+        tables = _random_wave_group(rng, T, B, K, 12, faults)
+        kw = dict(num_classes=K, use_kernel=use_kernel)
+        with x64(), router_mod._quiet_donation():
+            want = unpacked(*_padded_tables(*tables), margin, **kw)
+            for scan in (router_mod._wave_scan, router_mod._wave_scan_nodonate):
+                got = scan(router_mod._pack_wave(*tables, margin), **kw)
+                for g, e in zip(got, want):
+                    assert g.dtype == e.dtype and g.shape == e.shape
+                    np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+
+
+@pytest.mark.parametrize("entry", ["route_batch", "scheduler", "reference"])
+def test_one_route_places_one_wave_buffer(entry):
+    """A jit-plane group is one upload of its packed buffer's bytes,
+    mirrored into `BatchScheduler.stats`; the reference plane uploads
+    nothing."""
+    from repro.serving.router import _WAVE_PLANES, _bucket
+
+    K, L, clusters, B, seed = 4, 8, 5, 51, 3
+    wl, est, engine, router, qemb, R = _make_pool(K, L, clusters, B, seed)
+    if entry == "reference":
+        router = ThriftRouter(engine, est, num_classes=K, jit_waves=False)
+    budget = float(np.quantile(engine.costs, 0.6)) * 2
+    before = router.upload_stats()
+    if entry == "scheduler":
+        sched = BatchScheduler(router, max_batch=B, max_wait_s=0.0)
+        sched.submit_many(np.arange(B), qemb, budget)
+        sched.drain()
+        stats = sched.stats
+        assert stats["spec_jit"] == 1
+        assert {k: stats[k] for k in before} == router.upload_stats()
+    res = router.route_batch(np.arange(B), qemb, budget)   # the same group
+    after = router.upload_stats()
+    if entry == "reference":
+        assert after == before
+        return
+    n = 2 if entry == "scheduler" else 1
+    Tp, Bp = _bucket(res.schedule.shape[1], base=4), _bucket(B, base=8)
+    assert after["wave_uploads"] - before["wave_uploads"] == n
+    assert (after["wave_upload_bytes"] - before["wave_upload_bytes"]
+            == n * (_WAVE_PLANES * Tp + 2) * Bp * 8)
+
+
+def test_prewarmed_buckets_serve_a_burst_without_compiling():
+    """`prewarm_compile` packs and places exactly as traffic does, so a
+    served burst of ragged groups after it compiles nothing."""
+    from repro.analysis import CompileSentinel
+    from repro.serving import router as router_mod
+
+    K, L, clusters, B, seed = 4, 8, 5, 160, 19
+    wl, est, engine, router, qemb, R = _make_pool(K, L, clusters, B, seed)
+    budget = float(np.quantile(engine.costs, 0.6)) * 2
+    router.prewarm_compile(32, all_batch_buckets=True)
+    sentinel = CompileSentinel({"wave": router_mod._wave_scan})
+    sentinel.snapshot()
+    sched = BatchScheduler(router, max_batch=32, max_wait_s=0.0)
+    lo = 0
+    for n in (32, 7, 19, 32, 1, 25, 32, 12):
+        sched.submit_many(np.arange(lo, lo + n), qemb[lo:lo + n], budget)
+        sched.drain()
+        lo += n
+    assert sched.stats["spec_jit"] >= 8
+    sentinel.assert_no_new_compiles(detail="served burst after prewarm_compile")

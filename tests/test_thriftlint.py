@@ -116,8 +116,10 @@ class TestWalker:
 
     def test_finds_the_declared_entry_points(self, project):
         entries = {e.fn.qualname for e in project.jit_entries if e.fn}
-        assert {"_wave_scan_core", "_sur_greedy_scan_core",
+        assert {"_wave_scan_packed", "_sur_greedy_scan_core",
                 "xi_from_responses", "sample_pool_responses"} <= entries
+        # the wave loop is entered through its packed-buffer wrapper
+        assert "_wave_scan_core" in {f.qualname for f in project.reachable}
 
     def test_wrapper_assignment_idiom_resolves(self, project):
         # mc.py: `xi_from_responses_grouped = partial(jax.jit, ...)(core)`

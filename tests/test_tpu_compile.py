@@ -98,7 +98,8 @@ def test_device_program_compiles_for_v5e(name, one_chip, warmed_shapes):
 def test_belief_aggregate_compiles_natively(one_chip, warmed_shapes):
     """At the wave program's widest prefix expansion: B * (T + 1) rows."""
     K = DEPLOYMENT["classes"]
-    T, B = max(np.shape(args[0]) for args, _ in warmed_shapes["wave"])
+    P, B = max(np.shape(args[0]) for args, _ in warmed_shapes["wave"])
+    T = (P - 2) // router_mod._WAVE_PLANES       # packed (6 * T + 2, B)
     rows = B * (T + 1)
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     compiled = belief_aggregate_pallas.lower(
