@@ -11,7 +11,9 @@ are read with the same code (``lib/check.py``):
   references;
 * ``control``: in the program's place, the data-plane reference computed in
   float32 (the precision below the configuration's float64) on the arm set
-  of the planner's control, the best affordable arm alone.
+  of the planner's control, the best affordable arm alone; with feedback,
+  also the reference's fold and gate in float32, held against its float64
+  gates.
 
 A limit lies above every program reading and below every control reading.
 Prints one JSON line per seed. Exits non-zero without a TPU.
@@ -33,15 +35,17 @@ def readings(config, mix, seed, seconds):
     """``(program, control, info)`` readings of one seed's run."""
     from bench.lib import harness
 
-    dep, tr, _ = harness.prepare(config, mix, seed, seconds)
-    rec = harness.Recorder(False)
+    rec = harness.Recorder(False, feedback="feedback" in config)
+    dep, tr, _ = harness.prepare(config, mix, seed, seconds,
+                                 rec=rec if rec.feedback else None)
     with rec.installed():
         served = harness.serve(dep, tr, tr.n_warm, tr.n, seconds)
     lo, hi = tr.n_warm, tr.n
     out = harness.outcomes(dep, served, rec, lo, hi)
-    program = harness.compare(dep, tr, out, lo, hi)
-    control = harness.compare(dep, tr, out, lo, hi, control=True)
-    return program, control, {"due": hi - lo}
+    info = {"due": hi - lo}
+    program = harness.compare(dep, tr, out, lo, hi, rec=rec, info=info)
+    control = harness.compare(dep, tr, out, lo, hi, control=True, rec=rec)
+    return program, control, info
 
 
 def main(argv=None) -> int:

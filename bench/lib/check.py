@@ -18,6 +18,16 @@ each against its limit from the configuration's ``correct_limits``:
   probability below the reference SurGreedy's set for the same cluster and
   budget (``planref.plan_gap``).
 
+A deployment with online feedback (its configuration has ``feedback``)
+is compared under the reference's own replay of the loop (``fold``): each
+request against the snapshot of its cluster's estimate in force when its
+group was dispatched, and ``plan_xi_gap`` per snapshot against the least
+SurGreedy can return at the planner's Monte Carlo resolution
+(``fold.plan_gap``); and has a sixth number, where its limits name it:
+
+* ``gate_disagreements``: clusters, summed over the program's folds, whose
+  drift gate the program and the reference decided differently.
+
 ``readings`` computes them for any candidate outputs, so the control is
 read by the same code.
 """
@@ -26,6 +36,13 @@ from __future__ import annotations
 import numpy as np
 
 CHECKS = ("missing", "mismatched", "cost_gap", "over_budget", "plan_xi_gap")
+FEEDBACK_CHECKS = ("gate_disagreements",)
+
+
+def names(limits: dict) -> tuple:
+    """The numbers a configuration's limits hold: the five, and the
+    feedback loop's where its limits name them."""
+    return CHECKS + tuple(k for k in FEEDBACK_CHECKS if k in limits)
 
 
 def readings(done, predictions, stop_waves, costs, ref, planned_cost,
@@ -50,13 +67,13 @@ def readings(done, predictions, stop_waves, costs, ref, planned_cost,
 
 def verdict(values: dict, limits: dict) -> bool:
     """True when every number is within its limit."""
-    return all(values[k] <= limits[k] for k in CHECKS)
+    return all(values[k] <= limits[k] for k in names(limits))
 
 
 def lines(values: dict, limits: dict) -> list:
     """One line per number, with its limit, for standard error."""
-    return [f"check {k}: {values[k]!r} (limit {limits[k]!r})" for k in CHECKS]
+    return [f"check {k}: {values[k]!r} (limit {limits[k]!r})" for k in names(limits)]
 
 
 def as_json(values: dict, limits: dict) -> dict:
-    return {k: {"value": values[k], "limit": limits[k]} for k in CHECKS}
+    return {k: {"value": values[k], "limit": limits[k]} for k in names(limits)}

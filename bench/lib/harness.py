@@ -21,11 +21,12 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import check, planref, reference, stats, traffic as traffic_mod
+from . import check, fold, planref, reference, stats, traffic as traffic_mod
 from .peaks import peaks
 from .pool import Pool
 
@@ -167,14 +168,33 @@ def _answers_engine():
     return FixedAnswers
 
 
+KMEANS_RESTARTS = 10
+
+
+def _clusters(emb: np.ndarray, k: int) -> np.ndarray:
+    """The program's k-means over the history's embeddings from seeds 0 to
+    ``KMEANS_RESTARTS - 1``, the assignment of least inertia kept (from
+    seed 0 alone it merges three of ``hellaswag-api``'s 8 clusters; for
+    ``agnews-selfhosted`` seed 0 is already the least)."""
+    from repro.core.clustering import kmeans
+
+    best, best_inertia = None, np.inf
+    for seed in range(KMEANS_RESTARTS):
+        assign, cents = kmeans(emb, k, seed=seed)
+        inertia = float(((emb - cents[assign]) ** 2).sum())
+        if inertia < best_inertia:
+            best, best_inertia = assign, inertia
+    return best
+
+
 class Deployment:
     """The system under test for one configuration, and the benchmark's own
     calibration of the same history for the reference."""
 
     def __init__(self, config: dict):
-        from repro.core.clustering import kmeans
         from repro.core.estimation import SuccessProbEstimator
         from repro.serving import BatchScheduler, OracleArm, ThriftRouter
+        from repro.serving.feedback import FeedbackLog
 
         self.config = config
         pc = config["pool"]
@@ -182,26 +202,46 @@ class Deployment:
         self.budgets = [float(b) for b in config["budgets"]]
         table, emb, truth = self.pool.history(config["history"], config["history_seed"])
         self.calibration = reference.Calibration(table, emb, truth)
-        assign, _ = kmeans(emb, self.pool.num_clusters, seed=0)
+        self.history_counts = np.stack([(truth == c).sum() * np.ones(self.pool.num_arms)
+                                        for c in self.calibration.ids])
+        assign = _clusters(emb, self.pool.num_clusters)
         self.estimator = SuccessProbEstimator(table, emb, assign)
         arms = [OracleArm(f"llm-{i}", self.pool, i, metered=bool(pc["metered"]))
                 for i in range(self.pool.num_arms)]
         self.engine = _answers_engine()(arms)
         self.router = ThriftRouter(self.engine, self.estimator,
                                    num_classes=self.pool.num_classes)
+        fb = config.get("feedback")
+        self.feedback = None if fb is None else FeedbackLog(
+            self.estimator, delta=float(fb["delta"]),
+            drift_delta=float(fb["drift_delta"]),
+            probe_rate=float(fb["probe_rate"]), probe_seed=int(fb["probe_seed"]))
         sc = config["scheduler"]
         self.sched = BatchScheduler(
             self.router, max_batch=int(sc["max_batch"]),
-            max_wait_s=float(sc["max_wait_s"]), max_inflight=int(sc["max_inflight"]))
+            max_wait_s=float(sc["max_wait_s"]), max_inflight=int(sc["max_inflight"]),
+            feedback=self.feedback)
+
+    def traffic(self, mix: dict, seed: int, seconds: float,
+                rate: Optional[float] = None):
+        """The run's traffic (``traffic.generate``), with the drift arms a
+        drifting mix needs, from the benchmark's own calibration."""
+        arms = None
+        if traffic_mod.drifts(mix):
+            arms = traffic_mod.drift_arm_sets(mix, self.calibration.p,
+                                              self.pool.costs, self.pool.num_classes)
+        return traffic_mod.generate(mix, self.pool, self.budgets, seed, seconds,
+                                    rate=rate, drift_arms=arms)
 
     def plan_depth(self) -> int:
         return max(len(self.router.plans.plan(c, b).order)
                    for c in self.estimator.clusters for b in self.budgets)
 
     def warm_programs(self) -> dict:
-        """Plans for every (cluster, tier), and the wave programs of the
+        """Plans for every (cluster, tier), the wave programs of the
         (batch, depth) buckets this cell's traffic can reach (none for a
-        metered pool, which the reference plane serves)."""
+        metered pool, which the reference plane serves) and, with feedback,
+        the planner programs a drift replan can reach."""
         built = self.sched.prewarm(budgets=self.budgets)
         depth = self.plan_depth()
         waves = 0
@@ -209,22 +249,57 @@ class Deployment:
             waves = self.router.prewarm_compile(
                 int(self.config["scheduler"]["max_batch"]),
                 max_waves=depth, all_batch_buckets=True)
-        return {"plans": built, "plan_depth": depth, "wave_buckets": waves}
+        warm = {"plans": built, "plan_depth": depth, "wave_buckets": waves}
+        if self.feedback is not None:
+            warm["planner_calls"] = self.warm_planner()
+        return warm
+
+    # Monte Carlo sample counts whose compile buckets a replan reaches: the
+    # program's theta is (8 + 2 eps) / (eps^2 p*) ln(2 L^2 / delta), ~8.4e3 /
+    # p* at the router's eps, delta and 12 arms, so an estimate p* of the
+    # best affordable arm in [0.26, 1] takes the buckets of these two
+    PLANNER_THETAS = (16384, 32768)
+
+    def warm_planner(self) -> int:
+        """Run the batched planner once at every shape a drift replan can
+        take: one fold drifts 1 to C clusters and rebuilds their plans at
+        every tier, so G = tiers x drifted groups, at each theta bucket."""
+        import jax
+        from repro.core.selection import sur_greedy_many
+
+        sel = self.router.selector
+        tiers = np.asarray(self.budgets, np.float64)
+        C = len(self.estimator.clusters)
+        p = np.stack([st.p_hat for st in self.estimator.clusters.values()])
+        calls = 0
+        for d in range(1, C + 1):
+            ps = np.repeat(p[:d], tiers.size, axis=0)
+            budgets = np.tile(tiers, d)
+            for theta in self.PLANNER_THETAS:
+                sur_greedy_many(ps, sel.costs, budgets, self.pool.num_classes,
+                                jax.random.key(sel.seed), theta,
+                                use_kernel=sel.use_kernel)
+                calls += 1
+        return calls
 
 
 def prepare(config: dict, mix: dict, seed: int, seconds: float,
-            rate: Optional[float] = None, fault=None):
+            rate: Optional[float] = None, fault=None, rec=None):
     """Build the deployment, draw the run's traffic, warm every program it
     uses and serve the warm-up phase. Returns ``(deployment, traffic,
     warm)``. ``fault``, for the tests only, breaks the timed path (given
-    the deployment) before anything is planned or served."""
+    the deployment) before anything is planned or served. ``rec``, a
+    ``Recorder``, records the warm-up: a deployment with feedback needs it,
+    since what the warm-up folds in moves the estimates the window starts
+    from."""
     dep = Deployment(config)
-    tr = traffic_mod.generate(mix, dep.pool, dep.budgets, seed, seconds, rate=rate)
+    tr = dep.traffic(mix, seed, seconds, rate=rate)
     dep.engine.answers = tr.answers
     if fault is not None:
         fault(dep)
     warm = dep.warm_programs()
-    serve(dep, tr, 0, tr.n_warm, float(mix.get("warmup_s", 0.0)))
+    with rec.installed() if rec is not None else contextlib.nullcontext():
+        serve(dep, tr, 0, tr.n_warm, float(mix.get("warmup_s", 0.0)))
     return dep, tr, warm
 
 
@@ -240,12 +315,36 @@ class Recorder:
     for none, plane kind)``, for the comparison. Tuples of arrays, floats
     and strings leave the garbage collector's lists, so recording adds no
     tracked object per group. In a traced run also: ``bench.*`` profiler
-    spans and the host seconds of each span."""
+    spans and the host seconds of each span.
 
-    def __init__(self, trace: bool):
+    With ``feedback``, for the reference's replay of the loop, also:
+
+    * ``group_cids``: each routed group's cluster id per request (B,), the
+      program's own ids, beside ``groups``;
+    * ``id_rows``: ``(request ids, traffic rows)`` per submitted block;
+    * ``labels``: ``(folds before it, request ids, labels)`` per
+      ``FeedbackLog.record_many``;
+    * ``folds``: ``(routed groups before it, drifted cluster ids)`` per
+      ``FeedbackLog.apply``, the boundary's place among the groups;
+    * ``probes``: ``(request ids, arms)`` of each group's exploration probes;
+    * ``replans``: ``(perf_counter at start, plans rebuilt)`` per
+      ``PlanService.replan_stale``, and ``planner``: ``(perf_counter, theta
+      per group that affords an arm)`` per batched planner call;
+    * in a traced run the spans ``bench.fold`` (around ``record_many`` and
+      ``apply``) and ``bench.replan`` (around ``replan_stale``)."""
+
+    def __init__(self, trace: bool, feedback: bool = False):
         self.trace = trace
+        self.feedback = feedback
         self.groups: List[tuple] = []
         self.spans: Dict[str, list] = {}      # name -> [(start, end)]
+        self.group_cids: List[np.ndarray] = []
+        self.id_rows: List[tuple] = []
+        self.labels: List[tuple] = []
+        self.folds: List[tuple] = []
+        self.probes: List[tuple] = []
+        self.replans: List[tuple] = []
+        self.planner: List[tuple] = []
 
     @contextlib.contextmanager
     def installed(self):
@@ -279,6 +378,8 @@ class Recorder:
                 if pending.kind != "empty":
                     rec.groups.append((t, np.asarray(queries)[:, 2],
                                        pending.sched_T, str(pending.kind)))
+                    if rec.feedback:
+                        rec.group_cids.append(np.asarray(pending.cluster_ids))
                 return pending
             return wrapped
 
@@ -288,11 +389,88 @@ class Recorder:
             # calls those thousands of times a second
             patch(PendingRoute, "step", lambda o: span("bench.step", o))
             patch(PendingRoute, "result", lambda o: span("bench.finalize", o))
+        if self.feedback:
+            self._patch_feedback(patch, span)
         try:
             yield self
         finally:
             for cls, name, orig in reversed(patched):
                 setattr(cls, name, orig)
+
+    def _patch_feedback(self, patch, span):
+        from repro.core import selection
+        from repro.serving import BatchScheduler
+        from repro.serving.feedback import FeedbackLog
+        from repro.serving.plans import PlanService
+
+        rec = self
+        timed = (lambda name, o: span(name, o)) if self.trace else (lambda name, o: o)
+
+        # the arrays recorded in the window are kept by reference, never
+        # copied: the ids of a block and the traffic's rows and labels are
+        # arrays that neither the program nor the harness writes again
+        def submit_many(orig):
+            def wrapped(sched, payloads, *a, **k):
+                blk = orig(sched, payloads, *a, **k)
+                rec.id_rows.append((blk.request_ids, payloads[:, 2]))
+                return blk
+            return wrapped
+
+        def record_many(orig):
+            inner = timed("bench.fold", orig)
+
+            def wrapped(log, ids, labels):
+                rec.labels.append((len(rec.folds), ids, labels))
+                return inner(log, ids, labels)
+            return wrapped
+
+        def apply(orig):
+            inner = timed("bench.fold", orig)
+
+            def wrapped(log):
+                report = inner(log)
+                rec.folds.append((len(rec.groups), tuple(int(c) for c in report.drifted)))
+                return report
+            return wrapped
+
+        def observe(orig):
+            def wrapped(log, ids, clusters, schedule, responses, invoked, probes=None):
+                if probes is not None and len(probes[0]):
+                    rows = np.asarray(probes[0], np.int64)
+                    rec.probes.append((np.asarray(ids, np.int64)[rows],
+                                       np.asarray(probes[1], np.int64).copy()))
+                return orig(log, ids, clusters, schedule, responses, invoked,
+                            probes=probes)
+            return wrapped
+
+        def replan_stale(orig):
+            inner = timed("bench.replan", orig)
+
+            def wrapped(plans, *a, **k):
+                t = time.perf_counter()
+                rebuilt = inner(plans, *a, **k)
+                rec.replans.append((t, int(rebuilt)))
+                return rebuilt
+            return wrapped
+
+        def sur_greedy_many(orig):
+            def wrapped(ps, b, budgets, *a, **k):
+                t = time.perf_counter()
+                thetas = a[2] if len(a) > 2 else k["thetas"]
+                G = np.atleast_2d(ps).shape[0]
+                afford = np.broadcast_to(np.asarray(budgets, np.float64), (G,)) \
+                    >= np.min(b) - 1e-15
+                rec.planner.append((t, np.broadcast_to(
+                    np.asarray(thetas, np.int64), (G,))[afford].copy()))
+                return orig(ps, b, budgets, *a, **k)
+            return wrapped
+
+        patch(BatchScheduler, "submit_many", submit_many)
+        patch(FeedbackLog, "record_many", record_many)
+        patch(FeedbackLog, "apply", apply)
+        patch(FeedbackLog, "observe", observe)
+        patch(PlanService, "replan_stale", replan_stale)
+        patch(selection, "sur_greedy_many", sur_greedy_many)
 
 
 # ---------------------------------------------------------------------------
@@ -318,11 +496,23 @@ class Served:
 
 COUNTERS = ("requests", "batches", "completed", "spec_jit", "spec_reference",
             "flushes")
+# with feedback on: window counter -> the scheduler's stats key
+FEEDBACK_COUNTERS = {"feedback_labels": "feedback_labels",
+                     "feedback_applies": "feedback_applies",
+                     "feedback_drifts": "feedback_drifts",
+                     "batch_replans": "plan_batch_replans"}
 
 
-def _counters(sched) -> dict:
+def _counter_keys(sched) -> dict:
+    keys = {k: k for k in COUNTERS}
+    if sched.feedback is not None:
+        keys.update(FEEDBACK_COUNTERS)
+    return keys
+
+
+def _counters(sched, keys: dict) -> dict:
     st = sched.stats
-    return {k: float(st.get(k, 0)) for k in COUNTERS}
+    return {k: float(st.get(v, 0)) for k, v in keys.items()}
 
 
 class GcWatch:
@@ -387,8 +577,11 @@ def serve(dep: Deployment, tr, lo: int, hi: int, seconds: float,
             out["stop"][a:b] = blk.stop_waves
             out["cost"][a:b] = blk.costs
             out["latency_s"][a:b] = blk.latencies_s
+            if tr.labels:     # each answer read gets its true label back at once
+                sched.record_outcomes(blk.request_ids, tr.payloads[lo + a:lo + b, 1])
 
-    c0 = _counters(sched)
+    keys = _counter_keys(sched)
+    c0 = _counters(sched, keys)
     if on_open is not None:
         on_open()
     watch = GcWatch()
@@ -414,7 +607,7 @@ def serve(dep: Deployment, tr, lo: int, hi: int, seconds: float,
             sched.pump()
             harvest()
         t1_perf = time.perf_counter()
-        c1 = _counters(sched)
+        c1 = _counters(sched, keys)
     if on_close is not None:
         on_close()
     completed = int(c1["completed"] - c0["completed"])
@@ -428,7 +621,7 @@ def serve(dep: Deployment, tr, lo: int, hi: int, seconds: float,
         t0_perf=t0_perf, t1_perf=t1_perf, window_s=now - t0,
         completed_in_window=completed,
         backlog_at_close=due_by_close - completed,
-        counters={k: c1[k] - c0[k] for k in COUNTERS},
+        counters={k: c1[k] - c0[k] for k in keys},
         lag_mean_ms=float(lag.mean()), lag_max_ms=float(lag.max()),
         submits=len(lags), outputs=out, unfinished=len(pending),
         stalls=stalls, gc=watch.summary(),
@@ -461,11 +654,18 @@ def outcomes(dep: Deployment, served: Served, rec: Recorder, lo: int, hi: int):
 
 
 def compare(dep: Deployment, tr, out: dict, lo: int, hi: int,
-            control: bool = False) -> dict:
+            control: bool = False, rec: Optional[Recorder] = None,
+            info: Optional[dict] = None) -> dict:
     """Readings of the program's outputs against the references; with
     ``control`` the control's readings instead: the data-plane reference
     in float32 and the planner's best affordable arm alone, put in the
-    program's place."""
+    program's place. A deployment with feedback is compared through the
+    reference's replay of its loop (``compare_feedback``), which needs the
+    run's ``rec``, recorded from the warm-up on; it fills ``info``, where
+    given, with what the reference's replay saw."""
+    if dep.feedback is not None:
+        return compare_feedback(dep, tr, out, lo, hi, rec, control=control,
+                                info=info)
     costs = dep.pool.costs
     K = dep.pool.num_classes
     cal = dep.calibration
@@ -495,6 +695,127 @@ def compare(dep: Deployment, tr, out: dict, lo: int, hi: int,
                           float(costs.min()), gap)
 
 
+def replay_feedback(dep: Deployment, tr, rec: Recorder, dtype=np.float64,
+                    against: Optional[list] = None):
+    """The reference's run of the feedback loop over everything ``rec``
+    saw (``fold.replay``), its gates held against the program's, or against
+    ``against`` (calibration rows fired, per fold). Returns ``(replay,
+    gate_disagreements)``."""
+    cal = dep.calibration
+    if not rec.groups:
+        raise ValueError("nothing was routed")
+    rows = np.concatenate([q for _, q, _, _ in rec.groups])
+    group = np.repeat(np.arange(len(rec.groups)),
+                      [q.shape[0] for _, q, _, _ in rec.groups])
+    arm_set = np.zeros((rows.size, dep.pool.num_arms), bool)
+    at = 0
+    for _, q, sched_T, _ in rec.groups:
+        t_idx, b_idx = np.nonzero(sched_T >= 0)
+        arm_set[at + b_idx, sched_T[t_idx, b_idx]] = True
+        at += q.shape[0]
+    cluster = cal.nearest(tr.emb[rows])
+    # each of the program's cluster ids stands for the calibration row most
+    # of its requests fall in; an id no request was routed under, for none
+    cids = np.concatenate(rec.group_cids)
+    pairs, count = np.unique(np.column_stack([cids, cluster]), axis=0,
+                             return_counts=True)
+    name = {}
+    for (c, r), k in sorted(zip(map(tuple, pairs), count), key=lambda x: x[1]):
+        name[int(c)] = int(r)
+    boundaries = [(g, {name.get(c, -1 - c) for c in drifted})
+                  for g, drifted in rec.folds]
+    if against is not None:
+        boundaries = [(g, fired) for (g, _), fired in zip(boundaries, against)]
+    ids = np.concatenate([i for i, _ in rec.id_rows])
+    id_row = np.concatenate([r for _, r in rec.id_rows])
+    order = np.argsort(ids)
+    ids, id_row = ids[order], id_row[order]
+
+    def rows_of(req_ids):
+        at = np.searchsorted(ids, req_ids)
+        if np.any(at >= ids.size) or np.any(ids[np.minimum(at, ids.size - 1)] != req_ids):
+            raise ValueError("a request id that was never submitted")
+        return id_row[at]
+
+    labels = tr.payloads[:, 1]
+    for _, req_ids, sent in rec.labels:
+        if not np.array_equal(labels[rows_of(req_ids)], sent):
+            raise ValueError("a label sent back is not the request's truth")
+    label_events = [(f, rows_of(req_ids)) for f, req_ids, _ in rec.labels]
+    probe_arm = np.full(tr.n, -1, np.int64)
+    for req_ids, arms in rec.probes:
+        probe_arm[rows_of(req_ids)] = arms
+    rp, disagree = fold.replay(
+        cal.p, dep.history_counts, float(dep.config["feedback"]["drift_delta"]),
+        rows, group, cluster, arm_set, tr.answers, labels, dep.pool.costs,
+        dep.pool.num_classes, boundaries, label_events, probe_arm, dtype=dtype)
+    return rp, disagree
+
+
+def compare_feedback(dep: Deployment, tr, out: dict, lo: int, hi: int,
+                     rec: Recorder, control: bool = False,
+                     info: Optional[dict] = None) -> dict:
+    """``compare`` for a deployment with feedback: every request due in the
+    window is routed by the reference under the snapshot its own replay of
+    the loop had in force when the request's group was dispatched, and each
+    planned set is scored against the least SurGreedy can return on that
+    snapshot at Algorithm 3's Monte Carlo resolution (``fold.plan_gap``).
+    Adds ``gate_disagreements``: the clusters, summed over the program's
+    folds, whose gate the program and the reference decided differently. The control is the same replay in float32 beside
+    the float64 one's gates, its data plane in float32 on the planner's
+    best affordable arm alone."""
+    if rec is None or not rec.feedback:
+        raise ValueError("a deployment with feedback is compared from its record")
+    costs = dep.pool.costs
+    K = dep.pool.num_classes
+    rp, disagree = replay_feedback(dep, tr, rec)
+    n = hi - lo
+    pos = np.full(n, -1, np.int64)
+    win = (rp.rows >= lo) & (rp.rows < hi)
+    pos[rp.rows[win] - lo] = np.flatnonzero(win)
+    routed = pos >= 0
+    snap = np.zeros(n, np.int64)
+    snap[routed] = rp.snap[pos[routed]]
+    snaps_p = np.stack(rp.snaps_p)
+    budgets = tr.budgets[lo:hi]
+    arm_set = out["arm_set"]
+    answers = tr.answers[:, lo:hi].T
+    p = snaps_p[snap]
+    if control:
+        _, disagree = replay_feedback(dep, tr, rec, dtype=np.float32,
+                                      against=rp.fired_log)
+        arm_set = np.zeros_like(arm_set)
+        for s, b in set(zip(snap[routed].tolist(), budgets[routed].tolist())):
+            est = types.SimpleNamespace(
+                p=np.clip(snaps_p[s], reference.P_FLOOR, 1.0 - reference.P_FLOOR))
+            arm_set[routed & (snap == s) & (budgets == b)] = planref.best_single(
+                est, costs, b)
+    ref = [np.zeros(n, np.int64), np.zeros(n, np.int64), np.zeros(n, np.float64)]
+    if control:
+        ref = list(reference.route(p, arm_set, answers, costs, K))
+    else:
+        ref[0][routed] = rp.pred[pos[routed]]
+        ref[1][routed] = rp.stop[pos[routed]]
+        ref[2][routed] = rp.cost[pos[routed]]
+    gap = fold.plan_gap(rp.snaps_p, costs, K, snap[routed], budgets[routed],
+                        arm_set[routed])
+    planned = (arm_set * costs[None, :]).sum(axis=1)
+    if control:
+        c_pred, c_stop, c_cost = reference.route(p, arm_set, answers, costs, K,
+                                                 dtype=np.float32)
+        got = (c_pred, c_stop, c_cost.astype(np.float64))
+        done = routed
+    else:
+        got = (out["pred"], out["stop"], out["cost"])
+        done = out["done"]
+    values = check.readings(done, *got, tuple(ref), planned, budgets,
+                            float(costs.min()), gap)
+    values["gate_disagreements"] = int(disagree)
+    if info is not None:
+        info.update(reference_fires=rp.fires, reference_snapshots=len(rp.snaps_p))
+    return values
+
+
 # ---------------------------------------------------------------------------
 # One run of one cell
 # ---------------------------------------------------------------------------
@@ -510,6 +831,9 @@ class Context:
     num_classes: int
     trace: Optional[dict]
     peaks: dict
+    num_arms: int = 0
+    replans: list = dataclasses.field(default_factory=list)   # plans rebuilt per replan call
+    planner: list = dataclasses.field(default_factory=list)   # theta per group, per planner call
 
 
 def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
@@ -522,8 +846,9 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
     import jax
 
     counter = CompileCount.get()
-    dep, tr, warm = prepare(config, mix, seed, seconds, fault=fault)
-    rec = Recorder(trace)
+    rec = Recorder(trace, feedback="feedback" in config)
+    dep, tr, warm = prepare(config, mix, seed, seconds, fault=fault,
+                            rec=rec if rec.feedback else None)
     tmp = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     try:
         programs_before = counter.n
@@ -560,7 +885,10 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
             shutil.rmtree(tmp, ignore_errors=True)
     lo, hi = tr.n_warm, tr.n
     out = outcomes(dep, served, rec, lo, hi)
-    values = compare(dep, tr, out, lo, hi)
+    t_ref = time.monotonic()
+    info: dict = {}
+    values = compare(dep, tr, out, lo, hi, rec=rec, info=info)
+    t_ref = time.monotonic() - t_ref
     limits = config["correct_limits"]
 
     dev = devices[0]
@@ -572,6 +900,13 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
           f"over {served.submits} submits")
     print(f"window counters: {json.dumps(served.counters)} | programs compiled or "
           f"loaded in window {compiles}")
+    if rec.feedback:
+        in_window = [r for t, r in rec.replans if served.t0_perf <= t < served.t1_perf]
+        print(f"feedback: drift events in window "
+              f"{traffic_mod.drift_events(mix, served.window_s)} | folds {len(rec.folds)} "
+              f"| replans in window {len(in_window)} rebuilding {sum(in_window)} plans "
+              f"| reference gates fired {info['reference_fires']} over "
+              f"{info['reference_snapshots']} snapshots")
     top = sorted(served.stalls, reverse=True)[:5]
     print(f"host stalls in window: {len(served.stalls)} serve-loop turns over "
           f"{1e3 * STALL_S:.0f} ms, longest (ms, at s) "
@@ -586,7 +921,8 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
                    "count": len(jax.devices()), "memory_peak_bytes": memory},
     }
     lat = stats.latency_ms(out["latency_s"], out["done"])
-    print(f"latency from due time: p50 {lat['p50_ms']!r} ms | p99 {lat['p99_ms']!r} ms")
+    print(f"latency from due time: p50 {lat['p50_ms']!r} ms | p99 {lat['p99_ms']!r} ms"
+          f" | reference's comparison {t_ref:.2f}s")
     if not trace:
         e2e = {"p50_ms": lat["p50_ms"],
                "served_qps": stats.served_qps(served.completed_in_window, served.window_s),
@@ -603,6 +939,9 @@ def run_cell(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
                    for k, v in rec.spans.items()},
             num_classes=dep.pool.num_classes, trace=reduced,
             peaks=peaks(dev.device_kind) if require_tpu else {},
+            num_arms=dep.pool.num_arms,
+            replans=[r for t, r in rec.replans if in_win(t)],
+            planner=[th for t, th in rec.planner if in_win(t)],
         )
         for m in cell_metrics(bench, cell["name"], "per_layer"):
             variant = m["name"].split(".", 1)[1] if "." in m["name"] else None

@@ -1,4 +1,6 @@
-"""The work of the wave program, counted from the request, not the code.
+"""The work of the device programs, counted from the request, not the code.
+
+The wave program:
 
 One routed group is B requests, a plan of T waves and K classes. The
 program has to read, once, per (wave, request) cell: the arm scheduled
@@ -23,3 +25,21 @@ def wave_bytes(B: int, T: int, K: int) -> int:
     must move."""
     B, T, K = int(B), int(T), int(K)
     return B * (T * CELL_IN + ROW_IN + ROW_OUT + K * BELIEF)
+
+
+def planner_bytes(G: int, thetas, L: int, K: int) -> int:
+    """HBM bytes one batched planner call over ``G`` (cluster, budget)
+    groups must move, by the paper's SurGreedy with Monte Carlo ``xi``: it
+    scores arm sets on ``theta_g`` sampled realisations of the L arms'
+    answers per group, so each group's (theta_g, L) answer table (int32
+    class ids) is read at least once; and it reads each group's estimate
+    and the prices (float64, L each) and writes its chosen set (int32, L)
+    and the ``xi`` of its three candidates (float64). ``K`` sets no byte:
+    a realisation's answer is one class id whatever K is. Counted from the
+    unpadded groups and their own ``theta``: compile buckets count
+    nothing."""
+    thetas = [int(t) for t in thetas]
+    if len(thetas) != int(G):
+        raise ValueError("one theta per group")
+    L = int(L)
+    return sum(t * L * 4 for t in thetas) + int(G) * (L * 8 * 2 + L * 4 + 3 * 8)

@@ -46,8 +46,9 @@ def measure(config: dict, mix: dict, seed: int, seconds: float,
     from bench.lib import check, harness, spans, stats, trace
     from bench.lib.peaks import peaks
 
-    dep, tr, warm = harness.prepare(config, mix, seed, seconds)
-    rec = harness.Recorder(True)
+    rec = harness.Recorder(True, feedback="feedback" in config)
+    dep, tr, warm = harness.prepare(config, mix, seed, seconds,
+                                    rec=rec if rec.feedback else None)
     tmp = tempfile.mkdtemp(prefix="bench_spans_")
     marks = {}
 
@@ -84,7 +85,7 @@ def measure(config: dict, mix: dict, seed: int, seconds: float,
 
     lo, hi = tr.n_warm, tr.n
     out = harness.outcomes(dep, served, rec, lo, hi)
-    values = harness.compare(dep, tr, out, lo, hi)
+    values = harness.compare(dep, tr, out, lo, hi, rec=rec)
     span_delta = spans.delta(marks["s0"], marks["s1"])
     cnt = {k: marks["c1"][k] - marks["c0"][k] for k in COUNTERS}
     fam = spans.families(span_delta, cnt)

@@ -13,6 +13,11 @@ the reference. The knee is the highest rate whose completions keep up
 (backlog at the close under 1% of the requests due) and whose p99 stays
 under the limit ``PERF.md`` records for the cell. Exits non-zero without a
 TPU.
+
+A deployment with feedback carries state from one window to the next (the
+estimates its labels move), and its check replays the loop from the
+calibration on: each rate then gets a deployment of its own, built, warmed
+and warmed up as ``run.py`` does, the programs compiled once per process.
 """
 import time
 
@@ -27,25 +32,39 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
+def _window(dep, tr, seconds, rec):
+    from bench.lib import harness
+
+    with rec.installed():
+        served = harness.serve(dep, tr, tr.n_warm, tr.n, seconds)
+    out = harness.outcomes(dep, served, rec, tr.n_warm, tr.n)
+    return served, out, harness.compare(dep, tr, out, tr.n_warm, tr.n, rec=rec)
+
+
 def sweep(cell, config, mix, rates, seconds, seed):
     from bench.lib import check, harness, stats
-    from bench.lib import traffic as traffic_mod
 
-    dep, _, warm = harness.prepare(config, mix, seed, 0.0, rate=rates[0])
-    print(f"sweep {cell['name']}: set-up {time.monotonic() - T_START:.1f}s, warm {warm}")
+    feedback = "feedback" in config
+    if not feedback:
+        dep, _, warm = harness.prepare(config, mix, seed, 0.0, rate=rates[0])
+        print(f"sweep {cell['name']}: set-up {time.monotonic() - T_START:.1f}s, "
+              f"warm {warm}")
     rows = []
     for i, rate in enumerate(rates):
-        tr = traffic_mod.generate(dict(mix, warmup_s=0.0), dep.pool, dep.budgets,
-                                  seed + 1 + i, seconds, rate=rate)
-        dep.engine.answers = tr.answers
-        rec = harness.Recorder(False)
-        with rec.installed():
-            served = harness.serve(dep, tr, 0, tr.n, seconds)
-        out = harness.outcomes(dep, served, rec, 0, tr.n)
-        values = harness.compare(dep, tr, out, 0, tr.n)
+        rec = harness.Recorder(False, feedback=feedback)
+        if feedback:
+            t0 = time.monotonic()
+            dep, tr, warm = harness.prepare(config, mix, seed + 1 + i, seconds,
+                                            rate=rate, rec=rec)
+            print(f"sweep {cell['name']} at {rate}: set-up "
+                  f"{time.monotonic() - t0:.1f}s, warm {warm}")
+        else:
+            tr = dep.traffic(dict(mix, warmup_s=0.0), seed + 1 + i, seconds, rate=rate)
+            dep.engine.answers = tr.answers
+        served, out, values = _window(dep, tr, seconds, rec)
         lat = stats.latency_ms(out["latency_s"], out["done"])
         row = {
-            "rate_qps": rate, "due": tr.n,
+            "rate_qps": rate, "due": tr.n - tr.n_warm,
             "completed_qps": served.completed_in_window / served.window_s,
             "backlog_at_close": served.backlog_at_close,
             "p50_ms": lat["p50_ms"], "p99_ms": lat["p99_ms"],
@@ -54,6 +73,8 @@ def sweep(cell, config, mix, rates, seconds, seed):
             "stalls": len(served.stalls),
             "longest_stall_ms": 1e3 * max([d for d, _ in served.stalls], default=0.0),
             "correct": check.verdict(values, config["correct_limits"]),
+            "checks": {k: values[k] for k in check.names(config["correct_limits"])},
+            "counters": served.counters,
         }
         rows.append(row)
         print(json.dumps(row))

@@ -1,8 +1,10 @@
 """The comparison that decides ``correct``: the plain reference agrees
 with the paper's loop, a sound run comes out correct, and the control and
-each fault of the timed path (the planner's included) come out not
-correct. Runs the harness on the CPU at a small rate (the look for a chip
-is skipped)."""
+each fault of the timed path (the planner's and the feedback loop's
+included) come out not correct. Runs the harness on the CPU at a small rate
+(the look for a chip is skipped, and so is the warm-up of the planner's
+compile buckets, which only keeps compiles out of a chip's window)."""
+import json
 import time
 
 import numpy as np
@@ -31,11 +33,13 @@ def test_reference_is_the_papers_adaptive_invocation():
         assert inv.cost == pytest.approx(cost[i], rel=1e-15, abs=0)
 
 
-def _run(cell, fault=None):
+def _run(cell, fault=None, monkeypatch=None, seconds=0.8, seed=2**31 + 11, **mix_keys):
     w, config, mix = harness.load_cell(cell, BENCH)
-    mix = dict(mix, rate_qps=300.0, warmup_s=0.3)
+    mix = dict(mix, **{"rate_qps": 300.0, "warmup_s": 0.3, **mix_keys})
+    if monkeypatch is not None:
+        monkeypatch.setattr(harness.Deployment, "warm_planner", lambda self: 0)
     devices = harness.start_jax(w["chips"], require_tpu=False)
-    return harness.run_cell(w, config, mix, 2**31 + 11, 0.8, False,
+    return harness.run_cell(w, config, mix, seed, seconds, False,
                             time.monotonic(), devices, BENCH,
                             require_tpu=False, fault=fault)
 
@@ -44,8 +48,8 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_sound_run_is_correct(cell):
-    res = _run(cell)
+def test_sound_run_is_correct(cell, monkeypatch):
+    res = _run(cell, monkeypatch=monkeypatch)
     assert res["correct"], res["checks"]
     assert list(res)[-1] == "checks"
     assert res["attempted"] > 100 and res["failed"] == 0
@@ -145,8 +149,168 @@ def test_a_broken_timed_path_is_not_correct(cell, fault, monkeypatch):
         "prediction_altered": _prediction_altered,
         "best_single_planned": _best_single_planned,
     }[fault]
-    res = _run(cell, fault=make(monkeypatch))
+    res = _run(cell, fault=make(monkeypatch), monkeypatch=monkeypatch)
     assert not res["correct"], res["checks"]
+
+
+# The drift cell at a size the CPU serves in seconds: drift every second
+# of a 3 s window, so the planned arms of 4 clusters fall at 1 s and are
+# restored at 2 s; the gates fire and the planner replans inside it.
+DRIFT = dict(rate_qps=500.0, warmup_s=0.5, drift_period_s=1.0)
+DRIFT_SEED = 2**31 + 29
+
+
+def _drift_run(monkeypatch, fault=None):
+    return _run("hellaswag-api-drift", fault=fault, monkeypatch=monkeypatch,
+                seconds=3.0, seed=DRIFT_SEED, **DRIFT)
+
+
+def _window_counters(out: str) -> dict:
+    line = next(ln for ln in out.splitlines() if ln.startswith("window counters: "))
+    return json.loads(line[len("window counters: "):].split(" | ")[0])
+
+
+def test_sound_drift_run_is_correct_with_gates_fired_and_replans(monkeypatch, capsys):
+    res = _drift_run(monkeypatch)
+    counters = _window_counters(capsys.readouterr().out)
+    assert res["correct"], res["checks"]
+    assert res["checks"]["gate_disagreements"]["value"] == 0
+    assert counters["feedback_drifts"] >= 1 and counters["batch_replans"] >= 1
+    assert counters["feedback_labels"] > 0 and counters["feedback_applies"] > 0
+
+
+def _first_drifted_cluster(dep):
+    """The program's id of the first cluster the window's first drift hits."""
+    _, _, mix = harness.load_cell("hellaswag-api-drift", BENCH)
+    tr = dep.traffic(dict(mix, **DRIFT), DRIFT_SEED, 3.0)
+    true_cluster = int(tr.drift[0][2][0])
+    return int(dep.estimator.lookup_batch(dep.pool.centers[true_cluster][None, :])[0])
+
+
+def _fold_drops_a_cluster(monkeypatch):
+    """The labels of one cluster, one the first drift hits, never fold."""
+    from repro.serving.feedback import FeedbackLog
+
+    orig = FeedbackLog.record_many
+
+    def apply(dep):
+        target = _first_drifted_cluster(dep)
+
+        def record_many(self, ids, labels):
+            ids, labels = np.asarray(ids), np.asarray(labels)
+            keep = np.asarray([
+                rid not in self._watch
+                or self._blocks[self._watch[rid][0]][0][self._watch[rid][1]] != target
+                for rid in ids.tolist()], bool)
+            return orig(self, ids[keep], labels[keep])
+        monkeypatch.setattr(FeedbackLog, "record_many", record_many)
+    return apply
+
+
+def _gate_never_fires(monkeypatch):
+    from repro.serving.feedback import FeedbackLog
+
+    return lambda dep: monkeypatch.setattr(FeedbackLog, "_moved",
+                                           lambda self, *a: False)
+
+
+def _replan_keeps_the_stale_plan(monkeypatch):
+    """Plans are keyed without the cluster's version: a drifted cluster
+    goes on being served the plan of its old estimate."""
+    from repro.serving.plans import PlanService
+
+    def key(self, cid, budget):
+        return (int(cid), float(budget), 0, self._cost_fp)
+    return lambda dep: monkeypatch.setattr(PlanService, "_plan_key", key)
+
+
+def _label_folded_twice(monkeypatch):
+    from repro.serving.feedback import FeedbackLog
+
+    orig = FeedbackLog.apply
+
+    def apply(self):
+        for buf in self._pending.values():
+            buf[0] = buf[0] * 2
+            buf[1] = buf[1] * 2
+        return orig(self)
+    return lambda dep: monkeypatch.setattr(FeedbackLog, "apply", apply)
+
+
+@pytest.mark.parametrize("fault", ["fold_drops_a_cluster", "gate_never_fires",
+                                   "replan_keeps_the_stale_plan",
+                                   "label_folded_twice"])
+def test_a_broken_feedback_loop_is_not_correct(fault, monkeypatch):
+    make = {
+        "fold_drops_a_cluster": _fold_drops_a_cluster,
+        "gate_never_fires": _gate_never_fires,
+        "replan_keeps_the_stale_plan": _replan_keeps_the_stale_plan,
+        "label_folded_twice": _label_folded_twice,
+    }[fault]
+    res = _drift_run(monkeypatch, fault=make(monkeypatch))
+    assert not res["correct"], res["checks"]
+
+
+def _planner_cut(monkeypatch, keep):
+    """The planner returns ``keep(result)`` in place of its SurGreedy pick."""
+    import dataclasses
+
+    from repro.core.selection import ThriftLLM
+
+    orig_one, orig_many = ThriftLLM.select, ThriftLLM.select_many
+
+    def cut(res):
+        return dataclasses.replace(res, chosen=np.asarray(keep(res), np.int64))
+
+    def apply(dep):
+        monkeypatch.setattr(ThriftLLM, "select",
+                            lambda self, *a, **k: cut(orig_one(self, *a, **k)))
+        monkeypatch.setattr(ThriftLLM, "select_many",
+                            lambda self, *a, **k: [cut(r) for r in orig_many(self, *a, **k)])
+    return apply
+
+
+def _too_few_draws(monkeypatch):
+    """The planner reads xi from a 64th of Algorithm 3's draws."""
+    from repro.core.selection import ThriftLLM
+
+    orig = ThriftLLM.theta
+    return lambda dep: monkeypatch.setattr(
+        ThriftLLM, "theta", lambda self, *a: max(1, orig(self, *a) // 64))
+
+
+@pytest.mark.parametrize("fault", ["gamma_greedy_only", "best_single_only",
+                                   "xi_greedy_only", "too_few_draws"])
+def test_a_planner_short_of_surgreedy_is_not_correct(fault, monkeypatch):
+    make = {
+        "gamma_greedy_only": lambda mp: _planner_cut(mp, lambda r: r.s2),
+        "best_single_only": lambda mp: _planner_cut(mp, lambda r: [r.l_star]),
+        "xi_greedy_only": lambda mp: _planner_cut(mp, lambda r: r.s1),
+        "too_few_draws": _too_few_draws,
+    }[fault]
+    res = _drift_run(monkeypatch, fault=make(monkeypatch))
+    assert not res["correct"], res["checks"]
+    assert res["checks"]["plan_xi_gap"]["value"] > res["checks"]["plan_xi_gap"]["limit"]
+
+
+def test_control_fails_the_drift_cell(monkeypatch):
+    w, config, mix = harness.load_cell("hellaswag-api-drift", BENCH)
+    monkeypatch.setattr(harness.Deployment, "warm_planner", lambda self: 0)
+    harness.start_jax(1, require_tpu=False)
+    rec = harness.Recorder(False, feedback=True)
+    dep, tr, _ = harness.prepare(config, dict(mix, **DRIFT), DRIFT_SEED + 1, 3.0,
+                                 rec=rec)
+    with rec.installed():
+        served = harness.serve(dep, tr, tr.n_warm, tr.n, 3.0)
+    out = harness.outcomes(dep, served, rec, tr.n_warm, tr.n)
+    info: dict = {}
+    sound = harness.compare(dep, tr, out, tr.n_warm, tr.n, rec=rec, info=info)
+    control = harness.compare(dep, tr, out, tr.n_warm, tr.n, control=True, rec=rec)
+    limits = config["correct_limits"]
+    assert check.verdict(sound, limits), sound
+    assert info["reference_fires"] >= 1
+    assert control["cost_gap"] > 1e3 * limits["cost_gap"]
+    assert control["plan_xi_gap"] > limits["plan_xi_gap"]
 
 
 def test_recorded_groups_carry_unpadded_shapes():

@@ -59,3 +59,87 @@ def test_plan_gap_reads_nought_on_the_references_own_sets():
     worse = sets.copy()
     worse[2] = planref.best_single(xis[1], costs, 1e-3)
     assert planref.plan_gap(xis, costs, pairs, worse) > 0.0
+
+
+def test_xi_is_the_share_of_draws_whose_vote_names_the_truth():
+    # the products over one-hot answers against a plain loop over the draws
+    rng = np.random.default_rng(12)
+    p = rng.uniform(0.2, 0.99, 6)
+    xi = planref.Xi(p, 3, np.random.default_rng(5), samples=64)
+    w, K = xi.w, 3
+    masks = rng.random((7, 6)) < 0.5
+    want = []
+    for m in masks:
+        credit = 0.0
+        for s in range(64):
+            names = np.argmax(xi.onehot_t[:, :, s], axis=0)      # each arm's answer
+            bel = np.full(K, xi.empty)
+            for k in range(K):
+                votes = m & (names == k)
+                if votes.any():
+                    bel[k] = w[votes].sum()
+            top = bel == bel.max()
+            credit += top[0] / top.sum()
+        want.append(credit / 64)
+    assert np.allclose(xi(masks), want, rtol=0, atol=1e-12)
+
+
+def test_grown_sets_read_as_their_masks_do():
+    rng = np.random.default_rng(5)
+    for t in range(6):
+        p = rng.uniform(0.05, 1.0, 12)
+        p[rng.integers(12, size=3)] = p[0]            # equal weights: exact ties
+        xi = planref.Xi(p, K, np.random.default_rng(t), 4096)
+        chosen = rng.random(12) < 0.4
+        cand = np.flatnonzero(~chosen)
+        masks = np.repeat(chosen[None], cand.size + 1, axis=0)
+        masks[np.arange(1, cand.size + 1), cand] = True
+        assert np.array_equal(xi.grown(chosen, cand), xi.per_draw(masks))
+
+
+def test_greedy_ends_without_theta_is_algorithm_1():
+    rng = np.random.default_rng(6)
+    costs = np.geomspace(4e-7, 2e-4, 12)
+    for t in range(4):
+        xi = _xi(rng.uniform(0.3, 0.95, 12))
+        for budget in (1e-5, 5e-5, 1e-4, 1e-3):
+            ends = planref.greedy_ends(xi, costs, budget)
+            assert ends.shape[0] == 1
+            assert np.array_equal(ends[0], planref._greedy(xi.p, costs, budget, xi))
+
+
+def test_greedy_ends_branch_on_arms_the_planners_draws_cannot_tell_apart():
+    # arms 1 and 2 are alike and only one fits beside arm 0: Algorithm 1
+    # takes the first, a planner reading xi from its own draws either
+    p = [0.9, 0.7, 0.7, 0.5]
+    costs = np.array([1e-5, 2e-5, 2e-5, 1e-3])
+    xi = _xi(p)
+    assert planref.greedy_ends(xi, costs, 3.5e-5).tolist() == [[True, True, False, False]]
+    ends = planref.greedy_ends(xi, costs, 3.5e-5, theta_n=8421)
+    assert sorted(map(tuple, ends.tolist())) == [(True, False, True, False),
+                                                  (True, True, False, False)]
+
+
+def test_theta_is_algorithm_3s_sample_count():
+    costs = np.array([1e-6, 1e-5, 1e-4])
+    p = np.array([0.5, 0.8, 0.99])
+    want = lambda ps: int(np.ceil(8.2 / (0.01 * ps) * np.log(2 * 9 / 0.01)))  # noqa: E731
+    assert planref.theta(p, costs, 2e-5) == want(0.8)
+    assert planref.theta(p, costs, 1e-3) == want(0.99)
+    assert planref.theta(p, costs, 1e-7) == want(1.0)
+
+
+def test_surgreedys_floor_lies_at_most_at_its_result_and_above_a_worse_set():
+    rng = np.random.default_rng(7)
+    p = rng.uniform(0.3, 0.95, 12)
+    costs = np.geomspace(4e-7, 2e-4, 12)
+    xi = _xi(p)
+    for budget in (1e-5, 1e-4, 1e-3):
+        n = planref.theta(p, costs, budget)
+        own = planref.sur_greedy(xi, costs, budget)
+        assert planref.sur_greedy_floor(xi, costs, budget, n) <= xi(own[None])[0]
+    n = planref.theta(p, costs, 1e-4)
+    single = planref.best_single(xi, costs, 1e-4)
+    assert planref.sur_greedy_floor(xi, costs, 1e-4, n) > xi(single[None])[0] + 0.05
+    nothing = xi(np.zeros((1, 12), bool))[0]
+    assert planref.sur_greedy_floor(xi, costs, 1e-8, n) == nothing

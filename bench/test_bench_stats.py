@@ -76,3 +76,65 @@ def test_wave_readers_refuse_a_trace_without_the_program(name):
                   peaks=peaks("TPU v5 lite"))
     with pytest.raises(LookupError):
         metric_reader(name)(ctx)
+
+
+@pytest.mark.parametrize("thetas", [[10525], [10525, 16000, 28000], [8421] * 40])
+def test_planner_bytes_depends_on_the_unpadded_groups_only(thetas):
+    from repro.core.mc import bucket_size
+
+    G, L = len(thetas), 12
+    want = sum(t * L * 4 for t in thetas) + G * (L * 16 + L * 4 + 24)
+    assert work.planner_bytes(G, thetas, L, 4) == want
+    assert work.planner_bytes(G, thetas, L, 77) == want
+    # the program's buckets (groups to a multiple of 8, samples to a power
+    # of two) would count more than the groups hold
+    Gp, Tp = bucket_size(G, 8), bucket_size(max(thetas), 256)
+    assert work.planner_bytes(Gp, [Tp] * Gp, L, 4) > want
+    with pytest.raises(ValueError):
+        work.planner_bytes(G + 1, thetas, L, 4)
+
+
+def _drift_ctx(programs):
+    from bench.lib.harness import Context
+
+    return Context(
+        variant="drift", counters={"requests": 40.0, "batches": 4.0},
+        groups=[("reference", 10, 3)] * 4,
+        spans={"bench.fold": np.array([[0.0, 0.001], [1.0, 1.003]]),
+               "bench.replan": np.array([[2.0, 2.05], [5.0, 5.03]]),
+               "bench.route": np.array([[0.0, 0.002]]),
+               "bench.step": np.array([[0.0, 0.002]])},
+        num_classes=4, trace={"programs": programs, "idle_pct": 99.5},
+        peaks=peaks("TPU v5 lite"), num_arms=12, replans=[10, 5],
+        planner=[np.full(10, 16000), np.full(5, 20000)])
+
+
+def test_feedback_and_planner_readers_read_a_synthetic_context():
+    from bench.lib.harness import metric_reader
+
+    ctx = _drift_ctx({"jit__sur_greedy_scan(3)": 2e-3, "jit__wave_scan_packed": 1.0})
+    read = {n: metric_reader(n + ".drift")(ctx) for n in (
+        "fold_host_ms", "replan_ms", "planner_device_us", "planner_roofline",
+        "rows_per_group", "route_host_ms", "device_idle_pct")}
+    assert read["fold_host_ms"] == pytest.approx(1e3 * 0.004 / 4)
+    assert read["replan_ms"] == pytest.approx(1e3 * 0.08 / 2)
+    assert read["planner_device_us"] == pytest.approx(1e6 * 2e-3 / 2)
+    moved = work.planner_bytes(10, [16000] * 10, 12, 4) + work.planner_bytes(
+        5, [20000] * 5, 12, 4)
+    assert read["planner_roofline"] == pytest.approx(100.0 * moved / 819e9 / 2e-3)
+    assert read["rows_per_group"] == 10.0
+    assert read["route_host_ms"] == pytest.approx(1e3 * 0.004 / 4)
+    assert read["device_idle_pct"] == 99.5
+    # no planner call and no fold in the window: nothing to read
+    ctx.planner, ctx.spans = [], {}
+    for n in ("fold_host_ms", "replan_ms", "planner_device_us", "planner_roofline"):
+        assert metric_reader(n + ".drift")(ctx) is None
+
+
+@pytest.mark.parametrize("name", ["planner_roofline.drift", "planner_device_us.drift"])
+def test_planner_readers_refuse_a_trace_without_the_planner(name):
+    from bench.lib.harness import metric_reader
+
+    ctx = _drift_ctx({"jit__wave_scan_packed(1)": 1e-3})
+    with pytest.raises(LookupError):
+        metric_reader(name)(ctx)
